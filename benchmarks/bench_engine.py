@@ -12,9 +12,11 @@ per-call-repacking path at 50% tile sparsity.
 The mesh section (DESIGN.md §10) re-runs the packed path under a
 1×2 (data, model) TP mesh — shard-local visit lists + NamedSharding'd
 caches — and checks the greedy streams stay bit-identical to the
-single-device packed path. It runs in a SUBPROCESS with 2 fake CPU
-devices so the parent bench keeps its 1-device environment and every
-other row stays comparable to prior PRs' BENCH_engine.json. At 25%
+single-device packed path. It runs in THIS process (one process per
+chip: a child could not reach a chip the parent already holds); main()
+gives the CPU platform 2 devices before JAX starts, every other row
+runs on the first, and on an accelerator the section needs 2 chips and
+fails without them. At 25%
 tile sparsity, NOT 50%: this reduced config prunes the whole d_ff grid
 at 0.5, which would make the bit-identity check vacuous for the
 sharded FFN reduction. The mesh section also measures the
@@ -55,17 +57,8 @@ writes BENCH_engine.json next to the repo root.
 """
 from __future__ import annotations
 
-import sys
-
-if __name__ == "__main__" and "--mesh-only" in sys.argv:
-    # the mesh subprocess: force devices before jax backend init
-    from benchmarks.common import ensure_fake_cpu_devices
-    ensure_fake_cpu_devices(2)
-
 import dataclasses
 import json
-import os
-import subprocess
 import time
 from typing import List
 
@@ -73,6 +66,8 @@ import numpy as np
 import jax
 
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import auto_mesh, ensure_fake_cpu_devices
 from repro.launch.serve import build_serving_params
 from repro.models import lm
 from repro.serve.engine import Engine, Request
@@ -115,21 +110,21 @@ MESH_SPARSITY = 0.25
 
 def bench_engine_mesh() -> List:
     """Packed path under a 1×2 TP mesh: tokens/s + bit-identity vs the
-    single-device packed path. Needs ≥2 devices — run via
-    ``--mesh-only`` (a subprocess of the full bench) or under your own
-    fake-device flag. The meshless reference runs HERE, in the same
-    process, so the comparison is apples-to-apples."""
+    single-device packed path, in the same process. Needs 2 devices
+    (main() gives the CPU platform 2); with fewer it raises."""
+    devs = jax.devices()
+    if len(devs) < 2:
+        raise RuntimeError(
+            f"the mesh 1x2 rows need 2 devices; this process has "
+            f"{len(devs)} ({devs[0].platform})")
     rows = []
-    if len(jax.devices()) < 2:
-        print("  mesh 1x2: skipped (<2 devices)")
-        return rows
     cfg0 = reduced(get_config(ARCH), layers=2, d_model=64, vocab=128)
     params0 = lm.init_params(jax.random.PRNGKey(0), cfg0)
     deploy = dict(path="packed", sparsity=MESH_SPARSITY,
                   block_k=8, block_n=8, verbose=False)
     p_ref, c_ref = build_serving_params(params0, cfg0, **deploy)
     _, ref_streams = _run_engine(p_ref, c_ref)
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = auto_mesh((1, 2), ("data", "model"))
     p, c = build_serving_params(params0, cfg0, mesh=mesh, **deploy)
     tok_s, streams = _run_engine(p, c, mesh=mesh)
     agree = int(streams == ref_streams)
@@ -150,34 +145,11 @@ def bench_engine_mesh() -> List:
                  f"tok_s={tok_s8:.2f};mesh=1x2;tp_comm=rs_ag_int8;"
                  f"single_device_agree={agree8};"
                  f"vs_psum_x{tok_s8 / tok_s:.3f}"))
-    return rows
-
-
-def _mesh_rows_subprocess() -> List:
-    """Run the mesh section in a child with 2 fake CPU devices so THIS
-    process keeps seeing 1 device (cross-PR row comparability; same
-    policy as tests/test_distribution.py)."""
-    p = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_engine", "--mesh-only"],
-        capture_output=True, text=True, env=dict(os.environ),
-        timeout=1200)
-    if p.returncode != 0:
-        err = p.stderr.strip().splitlines()
-        print(f"  mesh 1x2: subprocess failed (rc={p.returncode}): "
-              f"{err[-1] if err else '<no stderr>'}")
-        return []
-    rows = []
-    for line in p.stdout.splitlines():
-        if line.startswith("RESULT "):
-            rows = [tuple(r) for r in json.loads(line[len("RESULT "):])]
     for name, us, derived in rows:
-        tok_s = 1e6 / us
         agree = "single_device_agree=1" in derived
         comm = "rs_ag_int8" if "rs_ag_int8" in name else "psum"
-        print(f"  mesh 1x2 packed ({comm:10s}): {tok_s:7.1f} tok/s "
+        print(f"  mesh 1x2 packed ({comm:10s}): {1e6 / us:7.1f} tok/s "
               f"(vs single-device packed: {'==' if agree else '!='})")
-    if not rows:
-        print("  mesh 1x2: subprocess emitted no RESULT row")
     return rows
 
 
@@ -899,7 +871,7 @@ def bench_engine() -> List:
                          f"kernel_packed_agree={agree}"))
         rows.append((f"engine/packed_speedup/sp{sp:.2f}", 0.0,
                      f"x{speedup:.3f}_vs_percall_repack"))
-    rows.extend(_mesh_rows_subprocess())
+    rows.extend(bench_engine_mesh())
     rows.extend(bench_engine_load())
     rows.extend(bench_engine_qos())
     rows.extend(bench_engine_memory())
@@ -919,9 +891,8 @@ def rows_to_json(rows, path: str):
 
 
 def main():
-    if "--mesh-only" in sys.argv:       # the 2-fake-device subprocess
-        print("RESULT " + json.dumps(bench_engine_mesh()))
-        return
+    ensure_fake_cpu_devices(2)          # before JAX starts: mesh rows
+    enable_compile_cache()
     rows = bench_engine()
     rows_to_json(rows, "BENCH_engine.json")
 

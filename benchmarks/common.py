@@ -8,9 +8,6 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from repro.launch.mesh import ensure_fake_cpu_devices  # noqa: F401
-# (canonical impl lives in src so launch/serve.py shares it)
-
 QOS_CACHE = os.path.join("experiments", "qos_results.json")
 DRYRUN_DIR = os.path.join("experiments", "dryrun")
 
@@ -68,11 +65,8 @@ def time_fn(fn, *args, reps: int = 5, warmup: int = 2) -> float:
 
 
 def _block(r):
-    try:
-        import jax
-        jax.block_until_ready(r)
-    except Exception:
-        pass
+    import jax
+    jax.block_until_ready(r)
 
 
 def emit(name: str, us_per_call: float, derived: str):

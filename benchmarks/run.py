@@ -10,13 +10,15 @@ tracked across PRs.
 """
 from __future__ import annotations
 
-import sys
-
 
 def main() -> None:
-    # (the engine mesh row runs in bench_engine's own 2-fake-device
-    # subprocess; this process keeps 1 device so every other row stays
-    # comparable across PRs)
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.mesh import ensure_fake_cpu_devices
+
+    # before JAX starts: bench_engine's mesh rows need 2 devices; every
+    # other row runs on the first
+    ensure_fake_cpu_devices(2)
+    enable_compile_cache()
     from benchmarks import (
         bench_engine,
         bench_kernels,

@@ -67,6 +67,14 @@ def _fit_block(dim: int, want: int) -> int:
     return b
 
 
+def _value_dtype(entry: Params):
+    """Packed values keep the dtype of the weight they are packed from,
+    so a model served in bf16 is packed in bf16. An int8 ``qw`` entry
+    dequantizes to float32."""
+    w = entry.get("w") if isinstance(entry, dict) else None
+    return np.float32 if w is None else w.dtype
+
+
 def _dense_weight(entry: Params) -> Optional[np.ndarray]:
     """Materialize one matrix dict {w}|{qw} to dense fp32 (numpy)."""
     if not isinstance(entry, dict):
@@ -91,7 +99,8 @@ def pack_weight(w: np.ndarray, *, block_k: int, block_n: int,
                 act: Optional[str] = None,
                 quantize: bool = False,
                 tp: int = 1,
-                shard_kind: str = "col") -> PackedSASPWeight:
+                shard_kind: str = "col",
+                dtype=np.float32) -> PackedSASPWeight:
     """(K, N) or layer-stacked (L, K, N) dense weight (pruned tiles
     already zeroed) -> PackedSASPWeight. Stacked inputs are packed per
     layer and padded to a shared nnz (dup-last-visit zero padding).
@@ -105,6 +114,7 @@ def pack_weight(w: np.ndarray, *, block_k: int, block_n: int,
     so ``act`` must be None and ``bias`` is kept whole, to be added
     after the reduction). All (layer × shard) lists share one static
     nnz via the same dup-last-visit padding as the scan layout.
+    ``dtype``: dtype of the packed values (ignored when ``quantize``).
     """
     w = np.asarray(w, np.float32)
     if w.ndim == 2:
@@ -160,7 +170,8 @@ def pack_weight(w: np.ndarray, *, block_k: int, block_n: int,
                 None if ss[0] is None else np.stack(ss))
 
     per_layer = [_pad_stack(lp) for lp in packs]
-    vals = jnp.asarray(np.stack([p[0] for p in per_layer]))
+    vals = np.stack([p[0] for p in per_layer])
+    vals = jnp.asarray(vals if quantize else vals.astype(dtype))
     kn = jnp.asarray(np.stack([p[1] for p in per_layer]))
     scale = None if per_layer[0][2] is None else jnp.asarray(
         np.stack([p[2] for p in per_layer]).astype(np.float32))
@@ -185,7 +196,8 @@ def pack_ffn(w1: np.ndarray, w3: np.ndarray, w2: np.ndarray, *,
              b3: Optional[np.ndarray] = None,
              b2: Optional[np.ndarray] = None,
              quantize: bool = False,
-             tp: int = 1) -> PackedFFN:
+             tp: int = 1,
+             dtype=np.float32) -> PackedFFN:
     """Gated-FFN triple (each (d, F)/(F, d) or layer-stacked with a
     leading L axis) -> PackedFFN for the fused kernel.
 
@@ -196,6 +208,7 @@ def pack_ffn(w1: np.ndarray, w3: np.ndarray, w2: np.ndarray, *,
     yields a partial (M, d). b2 is NOT folded into the per-shard flush
     (it would be added tp times under the cross-shard reduction); it
     stays whole on the container for the driver to add once.
+    ``dtype``: dtype of w1v/w3v/w2v (ignored when ``quantize``).
     """
     w1 = np.asarray(w1, np.float32)
     squeeze = w1.ndim == 2
@@ -254,13 +267,14 @@ def pack_ffn(w1: np.ndarray, w3: np.ndarray, w2: np.ndarray, *,
 
     repacked = [_pad_visits(p) for p in packs]
 
-    def _stack(idx):
+    def _stack(idx, dt=None):
         a = np.stack([np.asarray(p[idx]) for p in repacked])
         if tp > 1:                         # (L·tp, …) -> (L, tp, …)
             a = a.reshape((L, tp) + a.shape[1:])
-        return jnp.asarray(a)
+        return jnp.asarray(a if dt is None else a.astype(dt))
 
-    w1v, w3v, w2v = _stack(0), _stack(1), _stack(2)
+    vdt = None if quantize else dtype
+    w1v, w3v, w2v = _stack(0, vdt), _stack(1, vdt), _stack(2, vdt)
     b1v, b3v = _stack(3), _stack(4)
     jv = _stack(7)
     if tp > 1:
@@ -297,24 +311,22 @@ def pack_ffn(w1: np.ndarray, w3: np.ndarray, w2: np.ndarray, *,
 
 
 def packed_matmul(x: jnp.ndarray, pw: PackedSASPWeight, *,
-                  block_m: int = 128,
-                  interpret: bool = True) -> jnp.ndarray:
+                  block_m: int = 128) -> jnp.ndarray:
     """(…, K) @ packed weight -> (…, N) through the tile-skip kernel,
     bias + activation fused into the flush. Zero per-call repacking."""
     scales = None if pw.scale is None else pw.scale
     return sasp_ops.sasp_matmul_packed(
         x, pw.vals, pw.kn, scales, n=pw.shape[1], block_m=block_m,
-        bias=pw.bias, act=pw.act, interpret=interpret)
+        bias=pw.bias, act=pw.act)
 
 
 def packed_ffn_apply(x: jnp.ndarray, pf: PackedFFN, *,
-                     block_m: int = 128,
-                     interpret: bool = True) -> jnp.ndarray:
+                     block_m: int = 128) -> jnp.ndarray:
     """Whole gated FFN in one fused kernel launch."""
     scales = None if pf.s1 is None else (pf.s1, pf.s3, pf.s2)
     return sasp_ops.fused_ffn_matmul(
         x, pf.w1v, pf.w3v, pf.w2v, pf.b1, pf.b3, pf.b2, scales=scales,
-        act=pf.act, block_m=block_m, interpret=interpret)
+        act=pf.act, block_m=block_m)
 
 
 # ---------------------------------------------------------------------------
@@ -384,7 +396,8 @@ def _pack_matrix_group(node: Params, names, cfg: ModelConfig,
         out[name] = pack_weight(
             w, block_k=cfg.sasp.block_k, block_n=cfg.sasp.block_n,
             bias=bias, act=act_for.get(name), quantize=quantize,
-            tp=tp, shard_kind=kinds.get(name, "col"))
+            tp=tp, shard_kind=kinds.get(name, "col"),
+            dtype=_value_dtype(node[name]))
     return out or None
 
 
@@ -414,7 +427,8 @@ def _deploy_slot(slot: Params, cfg: ModelConfig, *, quantize: bool,
                 ffn["sasp_fused"] = pack_ffn(
                     w1, w3, w2, block_f=cfg.sasp.block_n, act=cfg.act,
                     b1=ffn["w1"].get("b"), b3=ffn["w3"].get("b"),
-                    b2=b2, quantize=quantize, tp=tp_f)
+                    b2=b2, quantize=quantize, tp=tp_f,
+                    dtype=_value_dtype(ffn["w1"]))
             else:
                 # per-matrix packed: act folds into w1's flush epilogue,
                 # the gate product (if any) stays in jnp (models/ffn.py)
@@ -725,12 +739,12 @@ def _reshard_ffn(pf: PackedFFN, tp: int) -> PackedFFN:
              "b1": np.zeros((1, bf), np.float32),
              "b3": np.zeros((1, bf), np.float32),
              "jv": np.full((1,), -1, np.int32)}
+        for n in ("w1v", "w3v", "w2v"):     # the container's dtype
+            z[n] = z[n].astype(arrs[n].dtype)
         if quant:
             zs = _zero_block_scale()
             for n in ("s1", "s3", "s2"):
                 z[n] = np.full((1,), zs, np.float32)
-            for n in ("w1v", "w3v", "w2v"):
-                z[n] = z[n].astype(np.int8)
         return z
 
     packs = []                              # [L][tp] dicts

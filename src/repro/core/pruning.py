@@ -12,6 +12,7 @@ The mask representation is per-weight: bool (KB, NB) with True = keep.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -43,8 +44,11 @@ def effective_blocks(shape: Tuple[int, int], bk: int, bn: int
     return min(bk, K), min(bn, N)
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2))
 def tile_l1(w: jnp.ndarray, bk: int, bn: int) -> jnp.ndarray:
-    """L1 norm per (bk × bn) tile. w: (..., K, N) -> (..., KB, NB)."""
+    """L1 norm per (bk × bn) tile. w: (..., K, N) -> (..., KB, NB).
+    Jitted so |w| in float32 is reduced as it is made, never stored: a
+    stack of full-width layers would otherwise hold a float32 copy."""
     *lead, K, N = w.shape
     KB, NB = K // bk, N // bn
     wb = jnp.abs(w.reshape(*lead, KB, bk, NB, bn).astype(jnp.float32))

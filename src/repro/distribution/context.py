@@ -3,11 +3,9 @@ select distributed implementations (EP MoE, SP attention) without
 threading the mesh through every call signature. No mesh set → every
 helper is a no-op and models run single-process (smoke tests, QoS tier).
 
-Also home of the ``shard_map`` compat shim: JAX moved shard_map from
-``jax.experimental.shard_map`` (kwarg ``check_rep``, ≤0.5) to
-``jax.shard_map`` (kwarg ``check_vma``, 0.6+). Every call site in this
-repo routes through :func:`shard_map` below so the supported-version
-window is one line wide (DESIGN.md §10).
+Also home of :func:`shard_map`, the one entry to ``jax.shard_map``
+(DESIGN.md §10): every call site routes through it, which the static
+analyzer's shim pass enforces.
 
 How the packed containers engage (DESIGN.md §9–§10 — format spec in
 ``core/sparse.py``): a TP-sharded ``PackedSASPWeight`` / ``PackedFFN``
@@ -29,38 +27,18 @@ itself.
 from __future__ import annotations
 
 import contextlib
-import inspect
 from typing import Optional, Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def _resolve_shard_map():
-    """(impl, replication-check kwarg name) for the running JAX."""
-    impl = getattr(jax, "shard_map", None)           # 0.6+ public API
-    if impl is None:
-        from jax.experimental.shard_map import shard_map as impl
-    try:
-        kwargs = inspect.signature(impl).parameters
-        kw = "check_vma" if "check_vma" in kwargs else "check_rep"
-    except (TypeError, ValueError):                  # exotic wrappers
-        kw = "check_rep"
-    return impl, kw
-
-
-_SHARD_MAP_IMPL, _CHECK_KW = _resolve_shard_map()
-
-
 def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check: bool = False):
-    """Version-portable ``shard_map``.
-
-    ``check`` maps to ``check_rep`` (JAX ≤0.5) / ``check_vma`` (0.6+);
-    the repo's bodies use untracked collectives (psum_scatter epilogues,
-    all_to_all dispatch), so they pass False everywhere.
-    """
-    return _SHARD_MAP_IMPL(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **{_CHECK_KW: check})
+    """``jax.shard_map`` with ``check_vma=check``. The repo's bodies use
+    untracked collectives (psum_scatter epilogues, all_to_all dispatch),
+    so they pass False everywhere."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 _ACTIVE_MESH: Optional[Mesh] = None

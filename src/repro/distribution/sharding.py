@@ -381,12 +381,3 @@ def pool_shardings(cfg: ModelConfig, mesh: Mesh, pool_shape):
         return NamedSharding(mesh, P(*spec))
 
     return jax.tree_util.tree_map_with_path(fn, pool_shape)
-
-
-def constraint(x, mesh: Mesh, *spec):
-    """with_sharding_constraint that degrades to no-op off-mesh."""
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, P(*spec)))
-    except Exception:
-        return x

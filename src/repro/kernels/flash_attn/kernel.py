@@ -1,4 +1,4 @@
-"""Flash-attention Pallas kernel (TPU target, interpret-validated).
+"""Flash-attention Pallas kernel (TPU target; interpreted on CPU).
 
 Complements the SASP GEMM kernels: attention is the other compute
 hot-spot of every assigned transformer. Grid = (batch·kv-heads·groups,
@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.dispatch import pallas_call
 
 NEG_INF = -1.0e30
 
@@ -64,8 +66,7 @@ def _flash_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def flash_attention(q, k, v, q_pos, kv_pos, *, window: int,
-                    block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    block_q: int = 128, block_k: int = 128):
     """q: (H, Sq, D); k/v: (H, Sk, D); positions absolute int32.
     Key j visible to query i iff 0 <= q_pos[i] - kv_pos[j] < window
     (window >= Sk => plain causal). Returns (H, Sq, D).
@@ -82,7 +83,7 @@ def flash_attention(q, k, v, q_pos, kv_pos, *, window: int,
     grid = (H, Sq // bq, Sk // bk)
     scale = D ** -0.5
 
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_flash_kernel, kv_blocks=Sk // bk, block_k=bk,
                           window=window, scale=scale),
         grid=grid,
@@ -100,5 +101,4 @@ def flash_attention(q, k, v, q_pos, kv_pos, *, window: int,
             pltpu.VMEM((bq, D), jnp.float32),     # acc
         ],
         out_shape=jax.ShapeDtypeStruct((H, Sq, D), q.dtype),
-        interpret=interpret,
     )(q_pos.astype(jnp.int32), kv_pos.astype(jnp.int32), q, k, v)

@@ -10,8 +10,8 @@ import jax.numpy as jnp
 from repro.kernels.flash_attn.kernel import flash_attention
 
 
-@functools.partial(jax.jit, static_argnames=("window", "interpret"))
-def mha(q, k, v, q_pos, kv_pos, *, window: int, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("window",))
+def mha(q, k, v, q_pos, kv_pos, *, window: int):
     """q: (B, Sq, H, D); k/v: (B, Sk, KH, D) with H % KH == 0 (GQA).
     Returns (B, Sq, H, D)."""
     B, Sq, H, D = q.shape
@@ -22,6 +22,5 @@ def mha(q, k, v, q_pos, kv_pos, *, window: int, interpret: bool = True):
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
     kf = kr.transpose(0, 2, 1, 3).reshape(B * H, -1, D)
     vf = vr.transpose(0, 2, 1, 3).reshape(B * H, -1, D)
-    o = flash_attention(qf, kf, vf, q_pos, kv_pos, window=window,
-                        interpret=interpret)
+    o = flash_attention(qf, kf, vf, q_pos, kv_pos, window=window)
     return o.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)

@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.dispatch import pallas_call
+
 
 def _int8_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref):
     k = pl.program_id(2)
@@ -38,7 +40,7 @@ def _int8_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref):
 
 def int8_gemm(x: jnp.ndarray, w_q: jnp.ndarray, scale: jnp.ndarray, *,
               block_m: int = 128, block_k: int = 128, block_n: int = 128,
-              out_dtype=None, interpret: bool = True) -> jnp.ndarray:
+              out_dtype=None) -> jnp.ndarray:
     """x: (M, K) fp; w_q: (K, N) int8; scale: (KB, NB) fp32 per-block.
     Kernel blocks must align with quant blocks (bk | quant_bk etc.); here
     we require the quant grid to equal the kernel grid for a scale to be
@@ -51,7 +53,7 @@ def int8_gemm(x: jnp.ndarray, w_q: jnp.ndarray, scale: jnp.ndarray, *,
     while M % bm:
         bm -= 1
     out_dtype = out_dtype or x.dtype
-    return pl.pallas_call(
+    return pallas_call(
         _int8_kernel,
         grid=(M // bm, NB, KB),
         in_specs=[
@@ -62,5 +64,4 @@ def int8_gemm(x: jnp.ndarray, w_q: jnp.ndarray, scale: jnp.ndarray, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        interpret=interpret,
     )(x, w_q, scale)

@@ -1,8 +1,6 @@
 """Jit'd wrapper for the dense weight-INT8 GEMM."""
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
@@ -10,15 +8,13 @@ from repro.core.quantization import QuantizedWeight
 from repro.kernels.int8_gemm.kernel import int8_gemm
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _int8_matmul_jit(x, w_q, scale, *, interpret):
-    return int8_gemm(x, w_q, scale, interpret=interpret)
+@jax.jit
+def _int8_matmul_jit(x, w_q, scale):
+    return int8_gemm(x, w_q, scale)
 
 
-def int8_matmul(x: jnp.ndarray, qw: QuantizedWeight, *,
-                interpret: bool = True) -> jnp.ndarray:
+def int8_matmul(x: jnp.ndarray, qw: QuantizedWeight) -> jnp.ndarray:
     """(…, K) @ QuantizedWeight -> (…, N), dequant fused in the kernel."""
     *lead, K = x.shape
-    y = _int8_matmul_jit(x.reshape(-1, K), qw.q, qw.scale,
-                         interpret=interpret)
+    y = _int8_matmul_jit(x.reshape(-1, K), qw.q, qw.scale)
     return y.reshape(*lead, qw.q.shape[-1]).astype(x.dtype)

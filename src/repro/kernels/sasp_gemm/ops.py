@@ -46,8 +46,10 @@ def kernel_block_list(mask: np.ndarray) -> np.ndarray:
 
 def build_kernel_weight(w: np.ndarray, mask: np.ndarray, bk: int, bn: int,
                         *, quantize: bool = False):
-    """Offline packing: (w_vals, block_kn[, scales]) for ``sasp_matmul``.
-    Padding entries (empty output columns) carry zero blocks."""
+    """Offline packing: (w_vals, block_kn[, scales]) for ``sasp_matmul``,
+    as host numpy arrays (a load-time deployment never stages float32
+    copies on the device). Padding entries (empty output columns) carry
+    zero blocks."""
     w = np.asarray(w, np.float32)
     mask = np.asarray(mask, bool)
     K, N = w.shape
@@ -60,12 +62,12 @@ def build_kernel_weight(w: np.ndarray, mask: np.ndarray, bk: int, bn: int,
     ]) if kn.shape[1] else np.zeros((1, bk, bn), np.float32)
 
     if not quantize:
-        return jnp.asarray(vals), jnp.asarray(kn), None
+        return vals, kn, None
     amax = np.abs(vals).max(axis=(1, 2))
     scales = (np.maximum(amax, 1e-12) / 127.0).astype(np.float32)
     q = np.clip(np.round(vals / scales[:, None, None]), -127, 127
                 ).astype(np.int8)
-    return jnp.asarray(q), jnp.asarray(kn), jnp.asarray(scales)
+    return q, kn, scales
 
 
 def pad_block_list(vals: np.ndarray, kn: np.ndarray,
@@ -98,7 +100,8 @@ def build_fused_ffn(w1: np.ndarray, w3: np.ndarray, w2: np.ndarray, *,
                     block_f: int, b1=None, b3=None, b2=None,
                     quantize: bool = False, nv_pad: Optional[int] = None,
                     return_visits: bool = False):
-    """Offline packing for the fused gated-FFN kernel.
+    """Offline packing for the fused gated-FFN kernel, as host numpy
+    arrays.
 
     w1/w3: (d, F) up-projections with pruned tiles already zeroed; w2:
     (F, d) down-projection likewise. A d_ff column-block j (width
@@ -186,32 +189,27 @@ def build_fused_ffn(w1: np.ndarray, w3: np.ndarray, w2: np.ndarray, *,
         w1v, s1 = q(w1v)
         w3v, s3 = q(w3v)
         w2v, s2 = q(w2v)
-        scales = (jnp.asarray(s1), jnp.asarray(s3), jnp.asarray(s2))
+        scales = (s1, s3, s2)
 
-    out = (jnp.asarray(w1v), jnp.asarray(w3v), jnp.asarray(w2v),
-           jnp.asarray(b1v), jnp.asarray(b3v), jnp.asarray(b2), scales)
+    out = (w1v, w3v, w2v, b1v, b3v, b2, scales)
     if return_visits:
-        out = out + (jnp.asarray(jv),)
+        out = out + (jv,)
     return out
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("act", "block_m", "interpret"))
-def _fused_ffn_jit(x, w1v, w3v, w2v, b1, b3, b2, scales, *, act, block_m,
-                   interpret):
+@functools.partial(jax.jit, static_argnames=("act", "block_m"))
+def _fused_ffn_jit(x, w1v, w3v, w2v, b1, b3, b2, scales, *, act, block_m):
     return sasp_fused_ffn(x, w1v, w3v, w2v, b1, b3, b2, act=act,
-                          block_m=block_m, scales=scales,
-                          interpret=interpret)
+                          block_m=block_m, scales=scales)
 
 
 def fused_ffn_matmul(x: jnp.ndarray, w1v, w3v, w2v, b1, b3, b2, *,
-                     scales=None, act: str = "silu", block_m: int = 128,
-                     interpret: bool = True) -> jnp.ndarray:
+                     scales=None, act: str = "silu",
+                     block_m: int = 128) -> jnp.ndarray:
     """(…, d) -> (…, d) gated FFN through the single fused kernel."""
     *lead, d = x.shape
     y = _fused_ffn_jit(x.reshape(-1, d), w1v, w3v, w2v, b1, b3, b2,
-                       scales, act=act, block_m=block_m,
-                       interpret=interpret)
+                       scales, act=act, block_m=block_m)
     return y.reshape(*lead, d).astype(x.dtype)
 
 
@@ -248,13 +246,11 @@ def _kn_from_bsr(w: BlockSparseWeight) -> Tuple:
         None if s is None else jnp.asarray(s)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("n", "block_m", "act", "interpret"))
+@functools.partial(jax.jit, static_argnames=("n", "block_m", "act"))
 def _sasp_matmul_jit(x, w_vals, block_kn, scales, bias=None, *, n,
-                     block_m, act=None, interpret):
+                     block_m, act=None):
     return sasp_gemm(x, w_vals, block_kn, n=n, block_m=block_m,
-                     scales=scales, bias=bias, act=act,
-                     interpret=interpret)
+                     scales=scales, bias=bias, act=act)
 
 
 def _kn_from_bsr_traced(w: BlockSparseWeight):
@@ -278,7 +274,7 @@ def _kn_from_bsr_traced(w: BlockSparseWeight):
 
 
 def sasp_matmul(x: jnp.ndarray, w: BlockSparseWeight, *,
-                block_m: int = 128, interpret: bool = True) -> jnp.ndarray:
+                block_m: int = 128) -> jnp.ndarray:
     """(…, K) @ BSR weight -> (…, N) through the Pallas tile-skip kernel.
     Works under tracing (scan-over-layers) via the padded flat list;
     serving engines should pre-pack the compact form with
@@ -287,28 +283,25 @@ def sasp_matmul(x: jnp.ndarray, w: BlockSparseWeight, *,
     x2 = x.reshape(-1, K)
     w_vals, block_kn, scales = _kn_from_bsr_traced(w)
     y = _sasp_matmul_jit(x2, w_vals, block_kn, scales, n=w.shape[1],
-                         block_m=block_m, interpret=interpret)
+                         block_m=block_m)
     return y.reshape(*lead, w.shape[1]).astype(x.dtype)
 
 
 def sasp_matmul_packed(x: jnp.ndarray, w_vals, block_kn, scales=None, *,
                        n: int, block_m: int = 128, bias=None,
-                       act: Optional[str] = None,
-                       interpret: bool = True) -> jnp.ndarray:
+                       act: Optional[str] = None) -> jnp.ndarray:
     """Pre-packed fast path (serving): inputs from build_kernel_weight.
     ``bias``/``act`` run as flush-time epilogues inside the kernel."""
     *lead, K = x.shape
     y = _sasp_matmul_jit(x.reshape(-1, K), w_vals, block_kn, scales, bias,
-                         n=n, block_m=block_m, act=act,
-                         interpret=interpret)
+                         n=n, block_m=block_m, act=act)
     return y.reshape(*lead, n).astype(x.dtype)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block_m", "block_k", "block_n",
-                                    "interpret"))
+                   static_argnames=("block_m", "block_k", "block_n"))
 def masked_matmul(x, w, mask, *, block_m: int = 128, block_k: int = 128,
-                  block_n: int = 128, interpret: bool = True):
+                  block_n: int = 128):
     """Dense-grid compute-skip variant (ablation)."""
     return sasp_gemm_masked(x, w, mask, block_m=block_m, block_k=block_k,
-                            block_n=block_n, interpret=interpret)
+                            block_n=block_n)

@@ -6,8 +6,7 @@ from __future__ import annotations
 import os
 
 import jax
-
-from repro.configs.base import MeshConfig, MULTI_POD, SINGLE_POD
+from jax.sharding import AxisType
 
 
 def ensure_fake_cpu_devices(n: int) -> None:
@@ -22,19 +21,18 @@ def ensure_fake_cpu_devices(n: int) -> None:
             f"--xla_force_host_platform_device_count={n} " + flags)
 
 
+def auto_mesh(shape, axes, *, devices=None):
+    """THE mesh constructor of this repo: ``jax.make_mesh`` with every
+    axis ``Auto``. The model code places data with
+    ``with_sharding_constraint`` and ``shard_map`` under the compiler's
+    sharding propagation, which needs Auto axes; ``jax.make_mesh``
+    itself defaults to Explicit axes."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(cfg: MeshConfig):
-    return jax.make_mesh(cfg.shape, cfg.axes)
-
-
-def make_test_mesh(n_devices: int = None, model: int = 2):
-    """Small mesh over however many (possibly fake) devices exist — used
-    by the subprocess multi-device tests."""
-    n = n_devices or len(jax.devices())
-    data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh(shape, axes)

@@ -5,6 +5,13 @@ requests through the batched engine.
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-32b --reduce \
       --sasp 0.5 --path packed --int8-kv --requests 8
 
+``--reduce`` (the default) shrinks widths for CPU runs; on a chip serve
+published widths cut in depth only, in the compute dtype:
+
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-32b \
+      --no-reduce --layers 4 --param-dtype bfloat16 --sasp 0.5 \
+      --path packed
+
 Paths (DESIGN.md §4 + §9):
   dense   — unpruned baseline.
   masked  — pruned tiles zeroed in place, matmuls stay dense.
@@ -22,7 +29,7 @@ import dataclasses
 import re
 import threading
 import time
-from typing import Callable, Optional, Sequence, Tuple
+from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 import jax
@@ -31,6 +38,7 @@ from repro.configs import SASPConfig, get_config, reduced
 from repro.core.pruning import prune_params
 from repro.core.sasp import bsr_overlay_from_masks, merge_overlay, \
     quantize_params
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serve.engine import Engine, Request
 from repro.serve.telemetry import Telemetry, pcts_ms
@@ -41,7 +49,7 @@ PATHS = ("dense", "masked", "bsr", "kernel", "packed")
 
 def build_serving_params(params, cfg, *, path: str, sparsity: float,
                          int8_weights: bool = False,
-                         block_k: int = 32, block_n: int = 32,
+                         block_k: int = 128, block_n: int = 128,
                          scope: str = "ffn", verbose: bool = True,
                          mesh=None):
     """Deploy `params` for serving along one execution path. Returns
@@ -102,7 +110,7 @@ def parse_mesh(spec: Optional[str]):
     if dp < 1 or tp < 1:
         raise SystemExit(
             f"--mesh axes must both be >= 1, got {spec!r}")
-    from repro.launch.mesh import ensure_fake_cpu_devices
+    from repro.launch.mesh import auto_mesh, ensure_fake_cpu_devices
     ensure_fake_cpu_devices(dp * tp)
     import jax
     if len(jax.devices()) < dp * tp:
@@ -111,7 +119,7 @@ def parse_mesh(spec: Optional[str]):
             f"{len(jax.devices())} (set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={dp * tp} before "
             "any jax import initializes the backend)")
-    return jax.make_mesh((dp, tp), ("data", "model"))
+    return auto_mesh((dp, tp), ("data", "model"))
 
 
 def parse_buckets(spec: Optional[str], cache_len: int
@@ -249,10 +257,68 @@ def check_ranks(ranks: Optional[int], mesh, profile: str = "tp"):
             f"--ranks")
 
 
-def main():
+@dataclasses.dataclass
+class ServeResult:
+    """What one launcher run served: the deployed params and config, the
+    mesh (None without ``--mesh``) and every submitted request (each
+    ends ``done``, ``rejected`` or ``failed``)."""
+    params: Any
+    cfg: Any
+    mesh: Any
+    requests: List[Request]
+
+
+def serving_config(args):
+    """The architecture's config, cut as the flags say: ``--reduce``
+    shrinks widths (CPU runs); otherwise only ``--layers`` cuts depth
+    and the published widths stay."""
+    cfg = get_config(args.arch)
+    if args.reduce:
+        cfg = reduced(cfg, layers=args.layers or 4, d_model=128,
+                      vocab=512)
+    elif args.layers:
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
+    if args.param_dtype:
+        cfg = dataclasses.replace(cfg, param_dtype=args.param_dtype)
+    if args.int8_kv:
+        cfg = dataclasses.replace(cfg, kv_quant=True)
+    return cfg
+
+
+def load_model(args):
+    """(params, cfg) for ``args``: :func:`serving_config` with random
+    weights from a fixed seed (or restored from ``--ckpt-dir``)."""
+    cfg = serving_config(args)
+    # one program: the weights are made in their own dtype on the
+    # device, with no float32 copy of a whole stack in between
+    params = jax.jit(lm.init_params, static_argnums=1)(
+        jax.random.PRNGKey(0), cfg)
+    if args.ckpt_dir:
+        mgr = CheckpointManager(args.ckpt_dir)
+        like = jax.eval_shape(lambda: {"params": params})
+        state, _ = mgr.restore(like)
+        params = state["params"]
+        print(f"restored step {mgr.latest_step()} from {args.ckpt_dir}")
+    return params, cfg
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-32b")
-    ap.add_argument("--reduce", action="store_true", default=True)
+    ap.add_argument("--reduce", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="shrink widths for a CPU run (reduced(): "
+                         "d_model 128, vocab 512, float32); "
+                         "--no-reduce serves the published widths")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the model to this many layers and change "
+                         "nothing else (default: 4 with --reduce, the "
+                         "published depth without)")
+    ap.add_argument("--param-dtype", choices=("float32", "bfloat16"),
+                    default=None,
+                    help="dtype the weights are made and served in "
+                         "(default: the config's; bfloat16 serves "
+                         "qwen3-32b in its compute dtype)")
     ap.add_argument("--ckpt-dir", default=None,
                     help="restore params from a CheckpointManager dir")
     ap.add_argument("--sasp", type=float, default=0.0)
@@ -418,7 +484,19 @@ def main():
     ap.add_argument("--metrics-interval", type=float, default=0.0,
                     help="print an aggregated metrics summary every N "
                          "seconds while serving (0 = off)")
-    args = ap.parse_args()
+    return ap
+
+
+def main(argv: Optional[Sequence[str]] = None, *,
+         model: Optional[Tuple[Any, Any]] = None) -> ServeResult:
+    """Serve one synthetic batch as the flags say. Exits non-zero when
+    any request ends failed — a rank the scheduler contained, or a host
+    the frontend gave up on, is still a failed run. ``model``: (params,
+    cfg) made by the caller (from ``load_model``) to serve in place of
+    loading them here."""
+    args = build_parser().parse_args(argv)
+    if args.layers is not None and args.layers < 1:
+        raise SystemExit(f"--layers must be >= 1, got {args.layers}")
 
     # BEFORE any backend-initializing jax call: may set XLA_FLAGS
     mesh = parse_mesh(args.mesh)
@@ -452,20 +530,8 @@ def main():
         draft_k=args.draft_k, draft_int8=args.draft_int8,
         kv_dedup_every=args.kv_dedup_every, cache_len=args.cache_len)
 
-    cfg = get_config(args.arch)
-    if args.reduce:
-        cfg = reduced(cfg, layers=4, d_model=128, vocab=512)
-    if args.int8_kv:
-        cfg = dataclasses.replace(cfg, kv_quant=True)
-
-    params = lm.init_params(jax.random.PRNGKey(0), cfg)
-    if args.ckpt_dir:
-        mgr = CheckpointManager(args.ckpt_dir)
-        like = jax.eval_shape(lambda: {"params": params})
-        state, _ = mgr.restore(like)
-        params = state["params"]
-        print(f"restored step {mgr.latest_step()} from {args.ckpt_dir}")
-
+    enable_compile_cache()
+    params, cfg = model if model is not None else load_model(args)
     params, cfg = build_serving_params(
         params, cfg, path=args.path, sparsity=args.sasp,
         int8_weights=args.int8_weights, scope=args.scope, mesh=mesh)
@@ -677,6 +743,12 @@ def main():
     for r in sorted(done, key=lambda r: r.rid)[:3]:
         print(f"  req {r.rid}: prompt[{len(r.prompt)}] -> "
               f"{r.out_tokens[:10]}…")
+    failed = [r for r in reqs if r.status == "failed"]
+    if failed:
+        raise SystemExit(
+            f"{len(failed)} of {len(reqs)} requests failed; first: "
+            f"req {failed[0].rid}: {failed[0].error}")
+    return ServeResult(params, cfg, mesh, reqs)
 
 
 if __name__ == "__main__":

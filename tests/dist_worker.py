@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.launch.mesh import auto_mesh
+
 
 def out(**kw):
     print("RESULT " + json.dumps(kw))
@@ -31,7 +33,7 @@ def mode_sharded_train():
 
     cfg = reduced(get_config("qwen3-32b"), layers=2, d_model=64,
                   vocab=128)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
     opt_cfg = AdamWConfig(lr=1e-3)
     opt = adamw_init(params, opt_cfg)
@@ -71,7 +73,7 @@ def mode_moe_ep():
     # dispatch identical token sets and must agree numerically
     cfg_nodrop = dataclasses.replace(
         cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
     slot = jax.tree.map(lambda a: a[0],
                         params["segments"][0]["slot0"])["ffn"]
@@ -100,7 +102,7 @@ def mode_moe_ep():
 def mode_grad_compress():
     from repro.train.grad_compress import compressed_psum
 
-    mesh = jax.make_mesh((2, 4), ("pod", "data"))
+    mesh = auto_mesh((2, 4), ("pod", "data"))
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 1024)) \
         * jnp.array([[1.0], [3.0]])      # different per pod
 
@@ -125,14 +127,14 @@ def mode_elastic_reshard():
     from repro.train.checkpoint import CheckpointManager
 
     state = {"w": jax.random.normal(jax.random.PRNGKey(0), (16, 8))}
-    mesh1 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh1 = auto_mesh((4, 2), ("data", "model"))
     sh1 = {"w": NamedSharding(mesh1, P("data", "model"))}
     st1 = jax.device_put(state, sh1)
     mgr = CheckpointManager(sys.argv[2])
     mgr.save(1, st1)
 
     # "elastic": restore on a DIFFERENT mesh shape
-    mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh2 = auto_mesh((2, 4), ("data", "model"))
     sh2 = {"w": NamedSharding(mesh2, P("data", "model"))}
     restored, _ = mgr.restore(jax.eval_shape(lambda: state),
                               shardings=sh2)
@@ -149,7 +151,7 @@ def mode_decode_sharded():
 
     cfg = reduced(get_config("gemma3-4b"), layers=4, d_model=64,
                   vocab=128)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
     B, S0 = 8, 16
     toks = jax.random.randint(jax.random.PRNGKey(1), (B, S0 + 4), 0, 128)
@@ -174,7 +176,7 @@ def mode_decode_sharded():
 def mode_collective_parser_ground_truth():
     from repro.analysis.hlo import collective_bytes
 
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = auto_mesh((8,), ("model",))
     L, M, N = 5, 64, 128
 
     def step(ws, x):
@@ -209,7 +211,7 @@ def mode_rs_ag_int8_ffn():
     p = ffn_init(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (16, 8, 64))
     y0 = ffn_apply(p, cfg, x)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     with mesh, dctx.use_mesh(mesh):
         y1 = jax.jit(lambda pp, xx: ffn_apply(pp, cfg8, xx))(p, x)
     rel = float(jnp.max(jnp.abs(y0 - y1))
@@ -257,7 +259,7 @@ def mode_packed_serve_mesh():
     probe = packed_ffn_apply(jnp.ones((2, cfg0.d_model), jnp.float32), f0)
     fused_signal = float(jnp.abs(probe).max())
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = auto_mesh((2, 2), ("data", "model"))
     p2, c2 = build_serving_params(params0, cfg0, mesh=mesh, **deploy)
     s_mesh = streams(p2, c2, mesh=mesh)
     out(equal=int(s_ref == s_mesh), n=len(s_ref),
@@ -304,7 +306,7 @@ def mode_sched_mesh():
     results = {}
     stash = {}
     for name, shape in (("1x2", (1, 2)), ("2x2", (2, 2))):
-        mesh = jax.make_mesh(shape, ("data", "model"))
+        mesh = auto_mesh(shape, ("data", "model"))
         p, c = build_serving_params(params0, cfg0, mesh=mesh, **deploy)
         # EOS for request 1: first token in its stream with no earlier
         # occurrence, so the slot frees MID-DECODE and is refilled
@@ -388,7 +390,7 @@ def mode_paged_mesh():
     params0 = jax.tree.map(lambda a: a * 3.0, params0)
     deploy = dict(path="packed", sparsity=0.25, block_k=8, block_n=8,
                   scope="all", verbose=False)
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = auto_mesh((1, 2), ("data", "model"))
     p, c = build_serving_params(params0, cfg0, mesh=mesh, **deploy)
 
     def streams(**kv):

@@ -163,6 +163,7 @@ def _ffn_case(M, d, F, bk, bf, sp1, sp2):
     (32, 64, 128, 16, 16, 0.4, 0.4),
     (8, 32, 96, 8, 16, 0.7, 0.3),
     (7, 16, 32, 8, 8, 0.5, 0.5),     # ragged M
+    (130, 16, 32, 8, 8, 0.5, 0.5),   # > block_m: padded row blocks
 ])
 @pytest.mark.parametrize("act", ["silu", "gelu"])
 def test_fused_ffn_fp32_vs_masked_dense(M, d, F, bk, bf, sp1, sp2, act):

@@ -28,6 +28,7 @@ SWEEP = [
     (32, 64, 96, 16, 16, 0.9),
     (128, 256, 128, 64, 64, 0.6),
     (7, 16, 32, 8, 8, 0.4),          # ragged M
+    (130, 32, 32, 8, 8, 0.4),        # > block_m: padded row blocks
 ]
 
 

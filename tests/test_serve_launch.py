@@ -1,10 +1,18 @@
 """launch/serve.py argument validation: malformed --mesh and
 --ranks/--buckets misuse must fail with a clear usage error at parse
 time, not as a cryptic make_mesh / submesh shape failure downstream."""
+import dataclasses
+import os
+import subprocess
+import sys
+
 import jax
 import pytest
 
 from repro.launch.serve import check_ranks, parse_buckets, parse_mesh
+from repro.launch.mesh import auto_mesh
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.mark.parametrize("spec", ["2", "a,b", "1,2,3", ",2", "2,"])
@@ -30,13 +38,13 @@ def test_parse_mesh_none_and_valid(monkeypatch):
 
 
 def test_check_ranks_exceeding_dp_size_is_a_clear_error():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     with pytest.raises(SystemExit, match="exceeds the mesh's DP size"):
         check_ranks(2, mesh)
 
 
 def test_check_ranks_accepts_match_and_meshless():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     check_ranks(None, mesh)                   # omitted: mesh decides
     check_ranks(1, mesh)                      # equals DP size: fine
     check_ranks(7, None)                      # meshless: any count
@@ -157,3 +165,49 @@ def test_draft_flags_validate_identically_on_all_three_paths(
         _main_exits(path + ["--kv-pages", "16", "--kv-dedup-every",
                             "32"],
                     "--kv-dedup-every requires", monkeypatch)
+
+
+def test_compile_cache_defaults_to_fixed_repo_dir(monkeypatch):
+    from repro.launch import compile_cache as cc
+    monkeypatch.delenv(cc.ENV, raising=False)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        path = cc.enable_compile_cache()
+        assert path == os.path.join(REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_compile_cache_lands_in_env_dir(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set the helper sets nothing and a
+    compile is written there."""
+    code = ("import jax, jax.numpy as jnp\n"
+            "from repro.launch.compile_cache import enable_compile_cache\n"
+            "print(enable_compile_cache())\n"
+            "jax.jit(lambda x: jnp.sin(x) * 2)(jnp.ones(8)).block_until_ready()\n")
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0",
+               PYTHONPATH=os.path.join(REPO, "src"))
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, timeout=300)
+    assert p.returncode == 0, p.stderr
+    assert p.stdout.strip() == str(tmp_path)
+    assert any(tmp_path.iterdir())
+
+
+def test_no_reduce_cuts_depth_only():
+    """--no-reduce --layers N keeps the published widths; --reduce (the
+    default) shrinks them for CPU runs."""
+    from repro.configs import get_config
+    from repro.launch.serve import build_parser, serving_config
+    full = get_config("qwen3-32b")
+    cfg = serving_config(build_parser().parse_args(
+        ["--no-reduce", "--layers", "4", "--param-dtype", "bfloat16"]))
+    assert cfg.num_layers == 4 and cfg.param_dtype == "bfloat16"
+    assert cfg == dataclasses.replace(full, num_layers=4,
+                                      param_dtype="bfloat16")
+    small = serving_config(build_parser().parse_args(["--layers", "2"]))
+    assert small.num_layers == 2 and small.d_model == 128
+    assert serving_config(build_parser().parse_args([])).d_model == 128

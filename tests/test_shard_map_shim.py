@@ -1,27 +1,21 @@
-"""The shard_map compat shim (distribution/context.py) must resolve AND
-execute on every supported JAX. This runs in-process over a 1-device
-mesh — no subprocess, no ``slow`` marker — so the min-JAX CI job
-(``-m "not slow"`` on 0.4.x) exercises the check_rep ↔ check_vma kwarg
-mapping at call time, not just at import. The multi-device semantics are
-covered by tests/test_distribution.py (slow)."""
+"""The shard_map wrapper (distribution/context.py) must execute on the
+installed JAX. This runs in-process over a 1-device mesh — no
+subprocess, no ``slow`` marker — so the fast CI subset exercises the
+``check_vma`` call at call time, not just at import. The multi-device
+semantics are covered by tests/test_distribution.py (slow)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.distribution import context as dctx
-
-
-def test_shim_resolves_known_kwarg():
-    assert dctx._CHECK_KW in ("check_rep", "check_vma")
-    assert callable(dctx._SHARD_MAP_IMPL)
+from repro.launch.mesh import auto_mesh
 
 
 def test_shim_executes_on_current_jax():
-    """Calling through the shim must construct the underlying shard_map
-    with the right replication-check kwarg — a wrong kwarg raises at
-    this call, which is exactly the drift the min-JAX job watches."""
-    mesh = jax.make_mesh((1,), ("model",))
+    """Calling through the wrapper must construct ``jax.shard_map`` with
+    the kwarg the installed JAX takes — a wrong kwarg raises here."""
+    mesh = auto_mesh((1,), ("model",))
     x = jnp.arange(8.0, dtype=jnp.float32).reshape(2, 4)
 
     def body(xx):

@@ -1,10 +1,8 @@
 """Pass 2 — shim enforcement.
 
-Every shard_map use must route through the version-portability shim in
-``src/repro/distribution/context.py`` (it papers over the
-``jax.experimental.shard_map``/``check_rep`` vs ``jax.shard_map``/
-``check_vma`` API split).  This pass forbids, anywhere else in the
-repo:
+Every shard_map use must route through the one wrapper in
+``src/repro/distribution/context.py`` (the single place that sets
+``check_vma``).  This pass forbids, anywhere else in the repo:
 
 * ``import jax.experimental.shard_map`` (any form)
 * ``from jax.experimental import shard_map`` / ``from
