@@ -1,0 +1,10 @@
+"""The benchmark's tests import its modules (``bench/*.py``) and the
+program (``src/repro``) by path, as ``bench/run.py`` does."""
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+for p in (os.path.join(ROOT, "src"), BENCH):
+    if p not in sys.path:
+        sys.path.insert(0, p)
