@@ -17,9 +17,13 @@ import jax
 from jax.experimental import pallas as pl
 
 
-def pallas_call(kernel: Callable, **kw) -> Callable:
+def pallas_call(kernel: Callable, *, name: str, **kw) -> Callable:
     """``pl.pallas_call(kernel, **kw)`` with ``interpret`` decided by the
-    lowering platform (see module docstring)."""
+    lowering platform (see module docstring). ``name`` is the kernel's
+    one stable name: Mosaic's ``kernel_name``, and ``{"kernel": name}``
+    in the custom call's ``kernel_metadata``, which a profiler trace's
+    op text carries."""
+    kw.update(name=name, metadata={"kernel": name})
     compiled = pl.pallas_call(kernel, interpret=False, **kw)
     interpreted = pl.pallas_call(kernel, interpret=True, **kw)
 

@@ -86,6 +86,7 @@ def flash_attention(q, k, v, q_pos, kv_pos, *, window: int,
     return pallas_call(
         functools.partial(_flash_kernel, kv_blocks=Sk // bk, block_k=bk,
                           window=window, scale=scale),
+        name="flash_attn",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bq,), lambda h, i, j: (i,)),       # q positions

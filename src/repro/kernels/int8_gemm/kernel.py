@@ -54,7 +54,7 @@ def int8_gemm(x: jnp.ndarray, w_q: jnp.ndarray, scale: jnp.ndarray, *,
         bm -= 1
     out_dtype = out_dtype or x.dtype
     return pallas_call(
-        _int8_kernel,
+        _int8_kernel, name="int8_gemm",
         grid=(M // bm, NB, KB),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),

@@ -171,6 +171,7 @@ def sasp_gemm(x: jnp.ndarray, w_vals: jnp.ndarray, block_kn: jnp.ndarray,
     y = pallas_call(
         functools.partial(_sasp_kernel, nnz=nnz, act=act, scaled=scaled,
                           biased=biased),
+        name="sasp_matmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(Mp // bm, nnz),
@@ -258,6 +259,7 @@ def sasp_fused_ffn(x: jnp.ndarray, w1v: jnp.ndarray, w3v: jnp.ndarray,
     return pallas_call(
         functools.partial(_fused_ffn_kernel, nv=nv, act=act,
                           scaled=scaled),
+        name="sasp_ffn_fused",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(Mp // bm, nv),
@@ -314,7 +316,7 @@ def sasp_gemm_masked(x: jnp.ndarray, w: jnp.ndarray, mask: jnp.ndarray,
     bm, Mp = _row_blocks(M, block_m)
     out_dtype = out_dtype or x.dtype
     return pallas_call(
-        _masked_kernel,
+        _masked_kernel, name="sasp_matmul_masked",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(Mp // bm, NB, KB),

@@ -97,6 +97,7 @@ from typing import Callable, Iterator, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import MIXER_ATTN, ModelConfig
 from repro.models import lm
@@ -170,12 +171,29 @@ class Request:                          # is a mutable in-flight object
 # against repro.serve.telemetry.DECLARED_STATS.
 _STAT_KEYS = ("decode_steps", "admitted",
               "prefill_tokens", "prefill_tokens_skipped",
-              "reprefill_tokens", "generated_tokens",
+              "reprefill_tokens", "prefill_computed_tokens",
+              "generated_tokens",
               "continuous_refills", "preemptions",
               "resumes", "failed", "requeued",
               "cancelled", "deaths",
               "spec_rounds", "spec_draft_tokens",
               "spec_accepted_tokens", "spec_fallbacks")
+
+
+def profiler_span(name: str, **args) -> TraceAnnotation:
+    """The tracer's profiler sink (DESIGN.md §18): every engine and
+    page-pool span also enters the JAX profiler's trace, on the clock
+    of the device's events, as ``engine.<name>``."""
+    return TraceAnnotation("engine." + name, **args)
+
+
+def named_jit(fn: Callable, *bound) -> Callable:
+    """``jax.jit`` of ``fn`` with its leading arguments ``bound``,
+    compiled under ``fn``'s own name: a bare ``partial`` compiles as
+    ``jit__unknown``, which no trace reader can tell apart."""
+    p = partial(fn, *bound)
+    p.__name__ = fn.__name__
+    return jax.jit(p)
 
 
 def _exec_path_label(params, cfg: ModelConfig) -> str:
@@ -216,6 +234,15 @@ def _sample_tokens(logits: jnp.ndarray, key, temps: jnp.ndarray
     return jnp.where(temps > 0, samp, greedy)
 
 
+def _gather_slot_rows(caches, slot):
+    return jax.tree.map(lambda leaf: leaf[:, slot], caches)
+
+
+def _scatter_slot_rows(caches, saved, slot):
+    return jax.tree.map(lambda leaf, s: leaf.at[:, slot].set(s), caches,
+                        saved)
+
+
 class Engine:
     def __init__(self, params, cfg: ModelConfig, *, batch_slots: int = 4,
                  cache_len: int = 512, rng_seed: int = 0, mesh=None,
@@ -246,6 +273,7 @@ class Engine:
         self.telemetry = telemetry if telemetry is not None \
             else Telemetry()
         self._trace = self.telemetry.tracer
+        self._trace.annotate = profiler_span
         self.stats = self.telemetry.engine_stats(rank) \
             .declare(_STAT_KEYS)
         self.mesh = mesh
@@ -307,25 +335,22 @@ class Engine:
         self.on_token: Optional[Callable[[Request, int], None]] = None
         self._key = jax.random.PRNGKey(rng_seed)
         if self.pool is not None:
-            self._decode = jax.jit(partial(
-                self._paged_decode_step, cfg, self.pool.NB,
-                self.pool.page_len))
-            self._prefill = jax.jit(partial(
-                self._paged_prefill_write, cfg, cache_len))
-            self._prefill_past = jax.jit(partial(
-                self._paged_prefill_past_write, cfg))
+            self._decode = named_jit(self._paged_decode_step, cfg,
+                                     self.pool.NB, self.pool.page_len)
+            self._prefill = named_jit(self._paged_prefill_write, cfg,
+                                      cache_len)
+            self._prefill_past = named_jit(
+                self._paged_prefill_past_write, cfg)
         else:
-            self._decode = jax.jit(partial(self._decode_step, cfg))
-            self._prefill = jax.jit(partial(self._prefill_and_write, cfg,
-                                            cache_len))
+            self._decode = named_jit(self._decode_step, cfg)
+            self._prefill = named_jit(self._prefill_and_write, cfg,
+                                      cache_len)
         self._sample = jax.jit(_sample_tokens)
         # preemption resume: one-gather snapshot / one-scatter restore of
         # a slot's cache rows (slot index is traced — no per-slot
         # recompilation). Paged engines unmap pages instead (no copy).
-        self._snap = jax.jit(lambda caches, slot: jax.tree.map(
-            lambda leaf: leaf[:, slot], caches))
-        self._restore = jax.jit(lambda caches, saved, slot: jax.tree.map(
-            lambda leaf, s: leaf.at[:, slot].set(s), caches, saved))
+        self._snap = jax.jit(_gather_slot_rows)
+        self._restore = jax.jit(_scatter_slot_rows)
         # self-speculative decoding (DESIGN.md §17): the SAME weights
         # re-pruned at a higher sparsity (optionally int8) draft k
         # tokens per round into scratch pages; one full-fidelity verify
@@ -366,11 +391,10 @@ class Engine:
                     profile)
                 dparams = jax.device_put(dparams, dsh)
             self._draft = (dparams, dcfg)
-            self._draft_decode = jax.jit(partial(
+            self._draft_decode = named_jit(
                 self._paged_decode_step, dcfg, self.pool.NB,
-                self.pool.page_len))
-            self._verify = jax.jit(partial(
-                self._paged_spec_verify, cfg))
+                self.pool.page_len)
+            self._verify = named_jit(self._paged_spec_verify, cfg)
         # opportunistic cross-request dedup (ROADMAP item 1 leftover):
         # re-link identical already-resident pages every N steps
         self.kv_dedup_every = max(0, int(kv_dedup_every))
@@ -607,10 +631,16 @@ class Engine:
         if self.on_token is not None:
             self.on_token(req, tok)
 
+    def _read(self, *arrays) -> List[np.ndarray]:
+        """Device arrays to the host, under the one span name every
+        blocking device-to-host read carries."""
+        with self._trace.span("read", tid=self.rank):
+            return [np.asarray(a) for a in arrays]
+
     def _sample_host(self, logits, reqs: List[Request]) -> List[int]:
         temps = jnp.asarray([r.temperature for r in reqs], jnp.float32)
-        toks = self._sample(logits, self._next_key(), temps)
-        return [int(t) for t in np.asarray(toks)]
+        (toks,) = self._read(self._sample(logits, self._next_key(), temps))
+        return [int(t) for t in toks]
 
     # -- preemption (DESIGN.md §12) ------------------------------------
     def preempt_slot(self, slot: int, *, keep_kv: bool = True) -> Request:
@@ -736,21 +766,19 @@ class Engine:
         cache PAGES into the pool at each request's allocated pages
         (padding rows write to the trash page — no mask needed).
         Returns the last-token logits (G, V)."""
-        t0 = self._trace.t0()
-        if self.pool is not None:
-            dests = self.pool.dest_table([r.rid for r in reqs],
-                                         toks.shape[0])
-            logits_last, self.pool.data = self._prefill(
-                self.params, toks, poss, self.pool.data,
-                jnp.asarray(dests))
-        else:
-            logits_last, self.caches = self._prefill(
-                self.params, toks, poss, self.caches,
-                jnp.asarray(np.asarray(all_slots, np.int32)), valid)
-        self._trace.complete("prefill", t0, tid=self.rank,
-                             rids=[r.rid for r in reqs],
-                             rows=int(toks.shape[0]),
-                             S=int(toks.shape[1]))
+        rows, S = (int(n) for n in toks.shape)
+        self.stats["prefill_computed_tokens"] += rows * S
+        with self._trace.span("prefill", tid=self.rank,
+                              rids=[r.rid for r in reqs], rows=rows, S=S):
+            if self.pool is not None:
+                dests = self.pool.dest_table([r.rid for r in reqs], rows)
+                logits_last, self.pool.data = self._prefill(
+                    self.params, toks, poss, self.pool.data,
+                    jnp.asarray(dests))
+            else:
+                logits_last, self.caches = self._prefill(
+                    self.params, toks, poss, self.caches,
+                    jnp.asarray(np.asarray(all_slots, np.int32)), valid)
         return logits_last
 
     def _prefill_into_slot(self, slot: int, req: Request,
@@ -809,9 +837,9 @@ class Engine:
         temps = np.zeros((Gp,), np.float32)
         for g, r in enumerate(reqs):
             temps[g] = r.temperature
-        sampled = self._sample(logits_last, self._next_key(),
-                               jnp.asarray(temps))
-        nxts = [int(t) for t in np.asarray(sampled)[:G]]
+        (sampled,) = self._read(self._sample(
+            logits_last, self._next_key(), jnp.asarray(temps)))
+        nxts = [int(t) for t in sampled[:G]]
         now = time.monotonic()
         for slot, req, nxt, L in zip(slots, reqs, nxts, lens):
             assert self.slot_req[slot] is None, \
@@ -882,19 +910,19 @@ class Engine:
         past_bt = self.pool.prefix_table(rids, skip_pages, nrows)
         dests = self.pool.dest_table(rids, nrows,
                                      skip_pages=skip_pages)
-        t0 = self._trace.t0()
-        logits_last, self.pool.data = self._prefill_past(
-            self.params, jnp.asarray(toks), jnp.asarray(poss),
-            self.pool.data, jnp.asarray(past_bt), jnp.asarray(dests))
-        self._trace.complete("prefill", t0, tid=self.rank, rids=rids,
-                             rows=int(nrows), S=int(S), shared=True)
+        self.stats["prefill_computed_tokens"] += nrows * S
+        with self._trace.span("prefill", tid=self.rank, rids=rids,
+                              rows=int(nrows), S=int(S), shared=True):
+            logits_last, self.pool.data = self._prefill_past(
+                self.params, jnp.asarray(toks), jnp.asarray(poss),
+                self.pool.data, jnp.asarray(past_bt), jnp.asarray(dests))
         self._register_prompt(reqs, seqs)
         temps = np.zeros((nrows,), np.float32)
         for g, r in enumerate(reqs):
             temps[g] = r.temperature
-        sampled = self._sample(logits_last, self._next_key(),
-                               jnp.asarray(temps))
-        nxts = [int(t) for t in np.asarray(sampled)[:G]]
+        (sampled,) = self._read(self._sample(
+            logits_last, self._next_key(), jnp.asarray(temps)))
+        nxts = [int(t) for t in sampled[:G]]
         now = time.monotonic()
         for slot, req, nxt, seq in zip(slots, reqs, nxts, seqs):
             assert self.slot_req[slot] is None, \
@@ -931,8 +959,13 @@ class Engine:
         if self.admission == "drain" and len(free) < self.B:
             return                  # drain-batch baseline: wait for all
         take = min(len(free), len(self.queue))
-        if not take:
-            return
+        if take:
+            with self._trace.span("admission", tid=self.rank,
+                                  rids=[r.rid for r in self.queue[:take]]):
+                self._admit_into(free, take)
+
+    def _admit_into(self, free: List[int], take: int):
+        """Admit the first ``take`` queued requests into ``free`` slots."""
         popped = [self.queue.pop(0) for _ in range(take)]
         slots = free[:take]
         try:
@@ -1027,7 +1060,7 @@ class Engine:
     def step(self) -> List[Request]:
         """Admit queued requests, run one decode step, retire finished.
         Returns completed requests."""
-        with self._mesh_ctx():
+        with self._mesh_ctx(), self._trace.span("step", tid=self.rank):
             return self._step_inner()
 
     def _step_inner(self) -> List[Request]:
@@ -1067,42 +1100,42 @@ class Engine:
         finished: List[Request] = []
 
         if active:
-            last = np.zeros((self.B, 1), np.int32)
-            temps = np.zeros((self.B,), np.float32)
-            act = np.zeros((self.B,), bool)
-            eos = np.full((self.B,), -1, np.int64)
-            remaining = np.zeros((self.B,), np.int32)
-            for i in active:
-                req = self.slot_req[i]
-                last[i, 0] = req.out_tokens[-1]
-                temps[i] = req.temperature
-                act[i] = True
-                eos[i] = -1 if req.eos_id is None else req.eos_id
-                remaining[i] = req.max_new_tokens - len(req.out_tokens)
-
-            if self.pool is not None:
-                # speculating slots are masked AND their rows read/write
-                # the trash tables — the normal decode never touches
-                # their pages this step
-                bt = jnp.asarray(self.pool.block_table(
-                    [r.rid if (r is not None and i in active) else None
-                     for i, r in enumerate(self.slot_req)]))
-                nxt, done, self.pool.data, self._key = self._decode(
-                    self.params, jnp.asarray(last),
-                    jnp.asarray(self.pos, jnp.int32), self.pool.data, bt,
-                    self._key, jnp.asarray(temps), jnp.asarray(act),
-                    jnp.asarray(eos.astype(np.int32)),
-                    jnp.asarray(remaining))
-            else:
-                nxt, done, self.caches, self._key = self._decode(
-                    self.params, jnp.asarray(last),
-                    jnp.asarray(self.pos, jnp.int32), self.caches,
-                    self._key,
-                    jnp.asarray(temps), jnp.asarray(act),
-                    jnp.asarray(eos.astype(np.int32)),
-                    jnp.asarray(remaining))
-            nxt = np.asarray(nxt)               # (B,) int32 — the ONLY
-            done = np.asarray(done)             # per-token host traffic
+            with self._trace.span("inputs", tid=self.rank,
+                                  slots=len(active)):
+                last = np.zeros((self.B, 1), np.int32)
+                temps = np.zeros((self.B,), np.float32)
+                act = np.zeros((self.B,), bool)
+                eos = np.full((self.B,), -1, np.int32)
+                remaining = np.zeros((self.B,), np.int32)
+                for i in active:
+                    req = self.slot_req[i]
+                    last[i, 0] = req.out_tokens[-1]
+                    temps[i] = req.temperature
+                    act[i] = True
+                    eos[i] = -1 if req.eos_id is None else req.eos_id
+                    remaining[i] = req.max_new_tokens - len(req.out_tokens)
+                last, pos, temps, act, eos, remaining = (
+                    jnp.asarray(a) for a in (last, self.pos, temps, act,
+                                             eos, remaining))
+                if self.pool is not None:
+                    # speculating slots are masked AND their rows
+                    # read/write the trash tables — the normal decode
+                    # never touches their pages this step
+                    bt = jnp.asarray(self.pool.block_table(
+                        [r.rid if (r is not None and i in active) else None
+                         for i, r in enumerate(self.slot_req)]))
+            with self._trace.span("decode", tid=self.rank):
+                if self.pool is not None:
+                    nxt, done, self.pool.data, self._key = self._decode(
+                        self.params, last, pos, self.pool.data, bt,
+                        self._key, temps, act, eos, remaining)
+                else:
+                    nxt, done, self.caches, self._key = self._decode(
+                        self.params, last, pos, self.caches, self._key,
+                        temps, act, eos, remaining)
+            # (B,) int32 tokens and done flags: the ONLY per-token host
+            # traffic
+            nxt, done = self._read(nxt, done)
         else:
             # every live slot is speculating: still split the step key
             # once so the RNG key-state stays in lockstep with the
@@ -1110,21 +1143,22 @@ class Engine:
             # later draw identical randomness either way)
             self._next_key()
 
-        self.stats["decode_steps"] += 1
-        self.stats["generated_tokens"] += len(active)
-        self.telemetry.note_tokens(self.path_label, len(active))
-        for i in active:
-            req = self.slot_req[i]
-            self.pos[i] += 1
-            self._emit(req, int(nxt[i]))
-            if bool(done[i]):
-                req.done = True
-                req.status = "done"
-                req.t_done = time.monotonic()
-                if self.pool is not None:       # EOS frees the pages
-                    self.pool.free(req.rid)
-                finished.append(req)
-                self.slot_req[i] = None
+        with self._trace.span("emit", tid=self.rank, tokens=len(active)):
+            self.stats["decode_steps"] += 1
+            self.stats["generated_tokens"] += len(active)
+            self.telemetry.note_tokens(self.path_label, len(active))
+            for i in active:
+                req = self.slot_req[i]
+                self.pos[i] += 1
+                self._emit(req, int(nxt[i]))
+                if bool(done[i]):
+                    req.done = True
+                    req.status = "done"
+                    req.t_done = time.monotonic()
+                    if self.pool is not None:   # EOS frees the pages
+                        self.pool.free(req.rid)
+                    finished.append(req)
+                    self.slot_req[i] = None
         if specs:
             finished += self._run_spec_round(specs)
         if (self.kv_dedup_every
@@ -1188,13 +1222,23 @@ class Engine:
         and frees everything. On a promotion failure (pool exhausted
         mid-merge) the slot falls back to an exact re-prefill resume —
         rollback is always an unmap, never a copy."""
+        with self._trace.span("spec_round", tid=self.rank,
+                              slots=len(specs)) as span:
+            finished, emitted = self._spec_round(specs)
+            span.set_metadata(emitted=emitted)
+        if emitted:
+            self.telemetry.note_tokens("draft", emitted)
+        return finished
+
+    def _spec_round(self, specs: List[Tuple[int, Request, dict]]
+                    ) -> Tuple[List[Request], int]:
+        """The round itself: (finished requests, tokens emitted)."""
         from repro.serve.memory import ZERO_PAGE, TRASH_PAGE
         k = self.draft_k
         C, L, NB = self.cache_len, self.pool.page_len, self.pool.NB
         B = self.B
         dparams, _ = self._draft
         finished: List[Request] = []
-        t_round = self._trace.t0()
         emitted = 0
         try:
             slot_rids: List[Optional[int]] = [None] * B
@@ -1222,7 +1266,7 @@ class Engine:
                     dparams, jnp.asarray(cur),
                     jnp.asarray(pos_d), self.pool.data, dbt_j,
                     dkey, temps0, act_j, eos_none, rem_big)
-                drafts[t] = np.asarray(nxt)
+                (drafts[t],) = self._read(nxt)
                 cur = drafts[t].reshape(B, 1)
                 pos_d += 1
             toks = np.zeros((B, k + 1), np.int32)
@@ -1244,7 +1288,7 @@ class Engine:
                 self.params, jnp.asarray(toks), jnp.asarray(poss),
                 self.pool.data, jnp.asarray(verify_bt),
                 jnp.asarray(dests))
-            pred = np.asarray(pred)             # (B, k+1) target argmax
+            (pred,) = self._read(pred)          # (B, k+1) target argmax
             for i, req, got in specs:
                 P = int(self.pos[i])
                 a = 0
@@ -1311,11 +1355,7 @@ class Engine:
             # containment: a raise mid-round must not leak scratch
             for _, req, _ in specs:
                 self.pool.discard_scratch(req.rid)
-        if emitted:
-            self.telemetry.note_tokens("draft", emitted)
-        self._trace.complete("spec_round", t_round, tid=self.rank,
-                             slots=len(specs), emitted=emitted)
-        return finished
+        return finished, emitted
 
     # -- failure containment (DESIGN.md §12/§14) -----------------------
     def _release_slot(self, slot: int) -> Request:

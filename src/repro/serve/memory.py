@@ -822,6 +822,31 @@ def masked_scatter_pages(data, caches, dests: jnp.ndarray):
                         is_leaf=lambda x: isinstance(x, attn_mod.KVCache))
 
 
+def read_pages(data, ids):
+    return jax.tree.map(lambda a: a[:, ids], data)
+
+
+def write_pages(data, ids, vals):
+    return jax.tree.map(lambda a, v: a.at[:, ids].set(v.astype(a.dtype)),
+                        data, vals)
+
+
+def scrub_pages(data, ids):
+    """Page scrub: recycled pages carry the previous owner's stale
+    contents — in particular pos values >= 0 that the ring mask would
+    attend to. Prefill and fault writes cover whole pages, but
+    decode-growth pages get only ONE token written, so they are reset
+    to the pristine zero page (zeros, pos = -1) first."""
+    return jax.tree.map(
+        lambda a: a.at[:, ids].set(a[:, ZERO_PAGE][:, None]), data)
+
+
+def copy_page(data, src, dst):
+    """Copy-on-write: duplicate one physical page (all layers) so a
+    divergent writer stops aliasing its shared prefix."""
+    return jax.tree.map(lambda a: a.at[:, dst].set(a[:, src]), data)
+
+
 def merge_page_slots(data, src, dst, lo, hi):
     """Copy the ring slots whose entry position lies in [lo, hi] from
     physical page ``src`` into page ``dst``, all layers at once — the
@@ -899,26 +924,10 @@ class PagedKVPool:
                 lambda s: np.zeros(
                     (s.shape[0], host_pages) + s.shape[2:], s.dtype),
                 jax.eval_shape(lambda: self.data))
-        self._read = jax.jit(
-            lambda data, ids: jax.tree.map(lambda a: a[:, ids], data))
-        self._write = jax.jit(
-            lambda data, ids, vals: jax.tree.map(
-                lambda a, v: a.at[:, ids].set(v.astype(a.dtype)),
-                data, vals))
-        # page scrub: recycled pages carry the previous owner's stale
-        # contents — in particular pos values >= 0 that the ring mask
-        # would attend to. Prefill and fault writes cover whole pages,
-        # but decode-growth pages get only ONE token written, so they
-        # are reset to the pristine zero page (zeros, pos = -1) first.
-        self._scrub = jax.jit(
-            lambda data, ids: jax.tree.map(
-                lambda a: a.at[:, ids].set(a[:, ZERO_PAGE][:, None]),
-                data))
-        # copy-on-write: duplicate one physical page (all layers) so a
-        # divergent writer stops aliasing its shared prefix
-        self._copy = jax.jit(
-            lambda data, src, dst: jax.tree.map(
-                lambda a: a.at[:, dst].set(a[:, src]), data))
+        self._read = jax.jit(read_pages)
+        self._write = jax.jit(write_pages)
+        self._scrub = jax.jit(scrub_pages)
+        self._copy = jax.jit(copy_page)
         # boundary-page promotion of a partially-accepted draft
         self._merge = jax.jit(merge_page_slots)
 
@@ -1107,30 +1116,28 @@ class PagedKVPool:
         host per call, one batched scatter from host per call."""
         spills = [(m[3], m[4]) for m in moves if m[0] == "spill"]
         faults = [(m[3], m[4]) for m in moves if m[0] == "fault"]
-        t0 = self._trace.t0()
         if spills:
-            dev_ids = jnp.asarray([d for d, _ in spills], jnp.int32)
-            out = self._read(self.data, dev_ids)
-            for leaf in jax.tree.leaves(out):   # overlap D2H copies
-                if hasattr(leaf, "copy_to_host_async"):
-                    leaf.copy_to_host_async()
-            vals = jax.device_get(out)
-            hs = [h for _, h in spills]
+            with self._trace.span("spill", cat="kv", pages=len(spills)):
+                dev_ids = jnp.asarray([d for d, _ in spills], jnp.int32)
+                out = self._read(self.data, dev_ids)
+                for leaf in jax.tree.leaves(out):   # overlap D2H copies
+                    if hasattr(leaf, "copy_to_host_async"):
+                        leaf.copy_to_host_async()
+                with self._trace.span("read", cat="kv"):
+                    vals = jax.device_get(out)
+                hs = [h for _, h in spills]
 
-            def put_host(hleaf, v):
-                hleaf[:, hs] = v
-                return hleaf
-            jax.tree.map(put_host, self._host, vals)
-            self._trace.complete("spill", t0, cat="kv",
-                                 pages=len(spills))
+                def put_host(hleaf, v):
+                    hleaf[:, hs] = v
+                    return hleaf
+                jax.tree.map(put_host, self._host, vals)
         if faults:
-            host_ids = [h for h, _ in faults]
-            dev_ids = jnp.asarray([d for _, d in faults], jnp.int32)
-            vals = jax.tree.map(lambda h: jnp.asarray(h[:, host_ids]),
-                                self._host)
-            self.data = self._write(self.data, dev_ids, vals)
-            self._trace.complete("fault", t0, cat="kv",
-                                 pages=len(faults))
+            with self._trace.span("fault", cat="kv", pages=len(faults)):
+                host_ids = [h for h, _ in faults]
+                dev_ids = jnp.asarray([d for _, d in faults], jnp.int32)
+                vals = jax.tree.map(lambda h: jnp.asarray(h[:, host_ids]),
+                                    self._host)
+                self.data = self._write(self.data, dev_ids, vals)
 
     # -- accounting ----------------------------------------------------
     def stats(self) -> MemoryStats:

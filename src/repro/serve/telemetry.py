@@ -12,18 +12,22 @@ Three pieces, one facade:
   merged bucket counts. Exports Prometheus text exposition.
 * :class:`SpanTracer` — a bounded ring buffer (``deque(maxlen=…)``) of
   host-side events: submit/queue/admit/prefill/preempt/spill/resume/
-  draft-verify round/token emission/host death/revive. Timestamps are
-  ``time.monotonic()`` taken on the host — the tracer never touches a
-  device value, never forces a sync, and never consumes RNG, so greedy
-  streams are bit-identical with tracing on or off. Exports Chrome
-  trace-event JSON (the ``traceEvents`` array format) loadable in
-  Perfetto / ``chrome://tracing``.
+  draft-verify round/token emission/host death/revive, plus the spans
+  of each engine step's phases. Timestamps are ``time.monotonic()``
+  taken on the host — the tracer never touches a device value, never
+  forces a sync, and never consumes RNG, so greedy streams are
+  bit-identical with tracing on or off. Exports Chrome trace-event JSON
+  (the ``traceEvents`` array format) loadable in Perfetto /
+  ``chrome://tracing``. Spans have a second sink, the profiler's
+  annotation (:attr:`SpanTracer.annotate`), which puts them on the
+  device trace's clock.
 * Per-path gauges the ROADMAP waits on: rolling tok/s per execution
   path (dense/masked/bsr/kernel/packed/int8/draft — item 4's
   SLO-conditioned autotuner keys fidelity choices on these) and the
   spec-decode acceptance EMA (item 3's adaptive draft-k input).
 
-Every hook is gated so a disabled tracer costs one attribute check, and
+Every hook is gated so a disabled tracer costs one attribute check (a
+span also enters its profiler sink, which the engine injects), and
 nothing here imports JAX — the analyzer's ``telemetry`` pass imports
 this module for :data:`DECLARED_STATS` and must stay device-free.
 
@@ -56,7 +60,7 @@ DECLARED_STATS = frozenset({
     # engine lifecycle
     "decode_steps", "admitted", "continuous_refills",
     "prefill_tokens", "prefill_tokens_skipped", "reprefill_tokens",
-    "generated_tokens",
+    "prefill_computed_tokens", "generated_tokens",
     # preemption / failure containment
     "preemptions", "resumes", "failed", "requeued", "cancelled",
     "deaths",
@@ -66,10 +70,6 @@ DECLARED_STATS = frozenset({
     # non-counter side objects surfaced through the same mapping
     "memory",
 })
-
-# execution-path labels for the rolling tok/s gauges
-PATH_LABELS = ("dense", "masked", "bsr", "kernel", "packed", "int8",
-               "draft")
 
 
 # ---------------------------------------------------------------------------
@@ -197,8 +197,9 @@ class Histogram:
 
 class RollingRate:
     """Windowed events/sec: a deque of (monotonic t, n) pairs trimmed
-    to the window on read. ``add`` is an append; ``per_s`` divides the
-    surviving event mass by the window."""
+    to the window on every add and read, so a rate nobody reads holds
+    at most one window of events. ``per_s`` divides the surviving event
+    mass by the window."""
 
     def __init__(self, window_s: float = 5.0):
         self.window_s = float(window_s)
@@ -206,16 +207,19 @@ class RollingRate:
 
     def add(self, n: int, t: Optional[float] = None) -> None:
         if n:
-            self._events.append((time.monotonic() if t is None else t,
-                                 n))
+            t = time.monotonic() if t is None else t
+            self._events.append((t, n))
+            self._trim(t)
 
-    def per_s(self, now: Optional[float] = None) -> float:
-        now = time.monotonic() if now is None else now
+    def _trim(self, now: float) -> None:
         horizon = now - self.window_s
         ev = self._events
         while ev and ev[0][0] < horizon:
             ev.popleft()
-        return sum(n for _, n in ev) / self.window_s
+
+    def per_s(self, now: Optional[float] = None) -> float:
+        self._trim(time.monotonic() if now is None else now)
+        return sum(n for _, n in self._events) / self.window_s
 
 
 class Ema:
@@ -440,13 +444,71 @@ class MetricsRegistry:
 # span tracer
 # ---------------------------------------------------------------------------
 
+class _NoSpan:
+    """The profiler sink of a tracer nobody injected one into."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return None
+
+    def set_metadata(self, **args) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def _no_annotation(name: str, **args) -> _NoSpan:
+    return _NO_SPAN
+
+
+class _RingSpan:
+    """A span recorded into the ring on exit, entering its profiler
+    annotation around the same interval."""
+
+    __slots__ = ("tracer", "name", "cat", "pid", "tid", "args", "inner",
+                 "t0")
+
+    def __init__(self, tracer, name, cat, pid, tid, args, inner):
+        self.tracer, self.name, self.cat = tracer, name, cat
+        self.pid, self.tid, self.args, self.inner = pid, tid, args, inner
+
+    def __enter__(self):
+        self.inner.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def set_metadata(self, **args) -> None:
+        """Args known only once the span's work is done."""
+        self.args.update(args)
+        self.inner.set_metadata(**args)
+
+    def __exit__(self, *exc):
+        self.tracer._push("X", self.name, self.cat, self.t0,
+                          time.monotonic() - self.t0, self.pid, self.tid,
+                          self.args)
+        return self.inner.__exit__(*exc)
+
+
 class SpanTracer:
     """Bounded ring buffer of host-side trace events. Disabled (the
-    default) every hook returns after ONE attribute check, and ``t0``
-    skips the clock read entirely — the hot path stays free. Enabled,
-    an event is a clock read + a tuple append into a ``deque(maxlen)``
-    (the bound: memory can never grow past ``capacity`` events however
-    long the server runs — oldest events fall off).
+    default) every hook returns after ONE attribute check — the hot
+    path stays free. Enabled, an event is a clock read + a tuple append
+    into a ``deque(maxlen)`` (the bound: memory can never grow past
+    ``capacity`` events however long the server runs — oldest events
+    fall off).
+
+    Spans (:meth:`span`) have a second sink, independent of the ring:
+    ``annotate(name, **args)`` returns a context manager with
+    ``set_metadata(**args)`` that is entered around the span. The
+    engine sets it to the JAX profiler's ``TraceAnnotation``, so a span
+    lands in a profiler trace on the same clock as the device's events
+    (this module imports no JAX); by default it does nothing. With the
+    ring disabled and the profiler not running a span costs one
+    attribute check plus an inactive annotation, and its args are never
+    formatted. Instants stay ring-only.
 
     Events carry monotonic timestamps only; nothing here reads a device
     value or forces a sync. Export is Chrome trace-event JSON
@@ -460,30 +522,29 @@ class SpanTracer:
         self.enabled = bool(enabled)
         self.buf: deque = deque(maxlen=self.capacity)
         self.dropped = 0                 # events pushed out of the ring
+        self.annotate: Callable = _no_annotation
 
     # -- hot-path hooks ------------------------------------------------
-    def t0(self) -> float:
-        """Span start stamp; 0.0 (never read) when disabled."""
-        return time.monotonic() if self.enabled else 0.0
+    def _push(self, ph, name, cat, ts, dur, pid, tid, args) -> None:
+        if len(self.buf) == self.capacity:
+            self.dropped += 1
+        self.buf.append((ph, name, cat, ts, dur, pid, tid, args))
 
     def instant(self, name: str, *, pid: int = 0, tid: int = 0,
                 cat: str = "serve", **args) -> None:
         if not self.enabled:
             return
-        if len(self.buf) == self.capacity:
-            self.dropped += 1
-        self.buf.append(("i", name, cat, time.monotonic(), 0.0, pid,
-                         tid, args))
+        self._push("i", name, cat, time.monotonic(), 0.0, pid, tid, args)
 
-    def complete(self, name: str, t0: float, *, pid: int = 0,
-                 tid: int = 0, cat: str = "serve", **args) -> None:
-        """A ``ph="X"`` span from ``t0`` (a :meth:`t0` stamp) to now."""
+    def span(self, name: str, *, pid: int = 0, tid: int = 0,
+             cat: str = "serve", **args):
+        """A ``ph="X"`` span over a ``with`` block, into the ring when
+        it is enabled and into the profiler sink always; ``as`` gives
+        an object whose ``set_metadata(**args)`` adds args late."""
+        inner = self.annotate(name, **args)
         if not self.enabled:
-            return
-        if len(self.buf) == self.capacity:
-            self.dropped += 1
-        self.buf.append(("X", name, cat, t0, time.monotonic() - t0,
-                         pid, tid, args))
+            return inner
+        return _RingSpan(self, name, cat, pid, tid, args, inner)
 
     # -- export --------------------------------------------------------
     def __len__(self) -> int:

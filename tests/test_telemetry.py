@@ -27,8 +27,8 @@ from repro.serve.frontend import ClusterFrontend, FrontendConfig, \
     make_local_hosts
 from repro.serve.scheduler import SchedulerConfig, ShardedScheduler
 from repro.serve.telemetry import CounterView, DECLARED_STATS, \
-    Histogram, HistSnapshot, MetricsRegistry, SpanTracer, Telemetry, \
-    TTFT_BOUNDS_S, merged_ttft_stats, nearest_rank, pcts_ms
+    Histogram, HistSnapshot, MetricsRegistry, RollingRate, SpanTracer, \
+    Telemetry, TTFT_BOUNDS_S, merged_ttft_stats, nearest_rank, pcts_ms
 
 KEY = jax.random.PRNGKey(0)
 
@@ -154,10 +154,67 @@ def test_tracer_ring_never_exceeds_capacity():
 
 def test_tracer_disabled_is_inert():
     tr = SpanTracer(capacity=8, enabled=False)
-    assert tr.t0() == 0.0                # no clock read when disabled
     tr.instant("x")
-    tr.complete("y", 0.0)
+    with tr.span("y", rows=3) as sp:     # no sink injected: a no-op
+        sp.set_metadata(emitted=1)
+    assert tr.span("z") is tr.span("w")  # no object made per span
     assert len(tr) == 0 and tr.events() == []
+
+
+class _Sink:
+    """A fake profiler sink: records (enter|exit, name, args)."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **args):
+        return _SinkSpan(self.log, name, dict(args))
+
+
+class _SinkSpan:
+    def __init__(self, log, name, args):
+        self.log, self.name, self.args = log, name, args
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, self.args))
+        return self
+
+    def set_metadata(self, **args):
+        self.args.update(args)
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, self.args))
+
+
+@pytest.mark.parametrize("ring", [True, False])
+def test_spans_reach_the_ring_and_the_sink_nested(ring):
+    tr = SpanTracer(capacity=64, enabled=ring)
+    sink = tr.annotate = _Sink()
+    with tr.span("step", tid=2):
+        with tr.span("prefill", tid=2, rows=12, S=256):
+            pass
+        tr.instant("token", tid=2)       # instants stay ring-only
+        with tr.span("spec_round", tid=2, slots=1) as sp:
+            sp.set_metadata(emitted=3)
+    assert [(k, n) for k, n, _ in sink.log] == [
+        ("enter", "step"), ("enter", "prefill"), ("exit", "prefill"),
+        ("enter", "spec_round"), ("exit", "spec_round"), ("exit", "step")]
+    assert sink.log[1][2] == {"rows": 12, "S": 256}
+    assert sink.log[4][2] == {"slots": 1, "emitted": 3}
+    ev = {e["name"]: e for e in tr.events()}
+    if not ring:
+        assert ev == {}
+        return
+    assert [e["name"] for e in tr.events()] == [
+        "prefill", "token", "spec_round", "step"]  # a span lands on exit
+    step = ev["step"]
+    for child in ("prefill", "spec_round"):
+        c = ev[child]
+        assert step["ts"] <= c["ts"]
+        assert c["ts"] + c["dur"] <= step["ts"] + step["dur"]
+        assert c["tid"] == 2 and c["ph"] == "X"
+    assert ev["prefill"]["ts"] + ev["prefill"]["dur"] <= ev["token"]["ts"]
+    assert ev["spec_round"]["args"] == {"slots": 1, "emitted": 3}
 
 
 def _check_chrome(trace):
@@ -182,8 +239,8 @@ def _check_chrome(trace):
 def test_chrome_trace_schema_roundtrip(tmp_path):
     tr = SpanTracer(capacity=64, enabled=True)
     tr.instant("submit", tid=1, rid=7)
-    t0 = tr.t0()
-    tr.complete("prefill", t0, tid=1, tokens=12)
+    with tr.span("prefill", tid=1, tokens=12):
+        pass
     tr.instant("admit", tid=0, cat="sched")
     path = tmp_path / "trace.json"
     assert tr.write(str(path)) == 3
@@ -241,6 +298,31 @@ def test_registry_prometheus_export():
     assert "serve_custom_total 7" not in text
 
 
+def test_rolling_rate_trims_on_add():
+    """A rate nobody reads (a server nobody scrapes) still holds at most
+    one window of events."""
+    r = RollingRate(window_s=1.0)
+    for i in range(2000):
+        r.add(1, t=i * 0.01)
+    assert len(r._events) <= 101
+    assert r.per_s(now=19.99) == pytest.approx(101.0)
+
+
+def test_telemetry_imports_without_jax():
+    """The analyzer's telemetry pass imports this module: no JAX."""
+    import os
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    code = ("import sys; sys.path.insert(0, %r); "
+            "import repro.serve.telemetry; "
+            "assert 'jax' not in sys.modules, 'jax imported'" % src)
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120)
+    assert p.returncode == 0, p.stderr
+
+
 def test_path_gauges_and_accept_ema():
     tel = Telemetry()
     assert tel.tok_s("packed") == 0.0
@@ -276,14 +358,40 @@ def _mk_requests(n, rng, max_new=6):
             for i in range(n)]
 
 
-@pytest.mark.parametrize("kw", [
-    {},                                                        # plain
-    dict(kv_pages=16, kv_page_len=8),                          # paged
-    dict(kv_pages=16, kv_page_len=8, kv_share=True),           # share
-    dict(kv_pages=16, kv_page_len=8, draft_sparsity=0.75,      # spec
-         draft_k=4),
-], ids=["plain", "paged", "share", "spec"])
-def test_engine_streams_bit_identical_with_tracing(kw):
+_PLAIN = {}
+_PAGED = dict(kv_pages=16, kv_page_len=8)
+_SHARE = dict(kv_pages=16, kv_page_len=8, kv_share=True)
+_SPEC = dict(kv_pages=16, kv_page_len=8, draft_sparsity=0.75, draft_k=4)
+# the engine's spans in a JAX profiler trace (the profiler sink)
+_PHASES = {"step", "admission", "prefill", "inputs", "decode", "read",
+           "emit"}
+
+
+def _profiled_span_names(tmp_path, fn):
+    """Run ``fn`` under the JAX profiler; the names of the host events
+    it recorded."""
+    import glob
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    pd = ProfileData.from_file(path)
+    names = {e.name for plane in pd.planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    return out, names
+
+
+@pytest.mark.parametrize("kw, sink", [
+    (_PLAIN, "ring"), (_PAGED, "ring"), (_SHARE, "ring"), (_SPEC, "ring"),
+    (_PLAIN, "profiler"), (_SHARE, "profiler"), (_SPEC, "profiler"),
+], ids=["plain", "paged", "share", "spec", "plain-profiler",
+        "share-profiler", "spec-profiler"])
+def test_engine_streams_bit_identical_with_tracing(kw, sink, tmp_path):
     cfg, params = _setup()
 
     def drive(trace):
@@ -294,12 +402,175 @@ def test_engine_streams_bit_identical_with_tracing(kw):
         return {r.rid: r.out_tokens for r in done}, eng
 
     ref, _ = drive(False)
+    if sink == "profiler":
+        (got, eng), names = _profiled_span_names(
+            tmp_path, lambda: drive(False))
+        assert got == ref
+        assert {"engine." + n for n in _PHASES} <= names, names
+        if "draft_sparsity" in kw:
+            assert "engine.spec_round" in names, names
+        assert len(eng.telemetry.tracer) == 0       # the ring stayed off
+        return
     got, eng = drive(True)
     assert got == ref
     names = set(_check_chrome(eng.telemetry.tracer.chrome()))
     assert {"submit", "admit", "prefill", "token"} <= names, names
+    assert _PHASES <= names, names
     if "draft_sparsity" in kw:
         assert "spec_round" in names, names
+
+
+def test_engine_step_phases_reach_the_profiler_sink():
+    """The spans of ``Engine.step``, in order, in a fake sink: an
+    admitting step, then a decode step that admits nothing."""
+    cfg, params = _setup()
+    eng = Engine(params, cfg, batch_slots=2, cache_len=64, buckets=(32,))
+    sink = eng.telemetry.tracer.annotate = _Sink()
+    rng = np.random.default_rng(1)
+    reqs = _mk_requests(2, rng, max_new=3)
+    for r in reqs:
+        eng.submit(r)
+    eng.step()
+    first = [(k, n) for k, n, _ in sink.log]
+    assert first == [
+        ("enter", "step"), ("enter", "admission"), ("enter", "prefill"),
+        ("exit", "prefill"), ("enter", "read"), ("exit", "read"),
+        ("exit", "admission"), ("enter", "inputs"), ("exit", "inputs"),
+        ("enter", "decode"), ("exit", "decode"), ("enter", "read"),
+        ("exit", "read"), ("enter", "emit"), ("exit", "emit"),
+        ("exit", "step")]
+    args = {n: a for k, n, a in sink.log if k == "exit"}
+    assert args["admission"] == {"rids": [0, 1]}
+    assert args["prefill"] == {"rids": [0, 1], "rows": 2, "S": 32}
+    assert args["inputs"] == {"slots": 2}
+    assert args["emit"] == {"tokens": 2}
+    del sink.log[:]
+    eng.step()
+    assert [n for k, n, _ in sink.log if k == "enter"] == [
+        "step", "inputs", "decode", "read", "emit"]
+    # greedy streams as with no sink at all
+    solo = Engine(params, cfg, batch_slots=2, cache_len=64, buckets=(32,))
+    ref = {r.rid: r.out_tokens for r in solo.run(
+        _mk_requests(2, np.random.default_rng(1), max_new=3))}
+    while eng.has_work():
+        eng.step()
+    assert {r.rid: r.out_tokens for r in reqs} == ref
+
+
+@pytest.mark.parametrize("kw", [dict(buckets=(16, 32)), _SHARE],
+                         ids=["bucketed", "share"])
+def test_prefill_computed_tokens_counts_rows_times_length(kw):
+    cfg, params = _setup()
+    eng = Engine(params, cfg, batch_slots=2, cache_len=64, **kw)
+    shapes = []
+    for name in ("_prefill", "_prefill_past"):
+        fn = getattr(eng, name, None)
+        if fn is None:
+            continue
+
+        def rec(params, toks, *a, _fn=fn):
+            shapes.append(tuple(toks.shape))
+            return _fn(params, toks, *a)
+        setattr(eng, name, rec)
+    rng = np.random.default_rng(3)
+    reqs = _mk_requests(4, rng)
+    if "kv_share" in kw:                 # a shared prefix: suffix prefill
+        for r in reqs[2:]:
+            r.prompt = np.concatenate([reqs[0].prompt[:16], r.prompt])
+        reqs[0].prompt = reqs[0].prompt[:20] if len(
+            reqs[0].prompt) >= 20 else np.concatenate(
+                [reqs[0].prompt, np.ones((20,), np.int32)])[:20]
+    eng.run(reqs)
+    assert shapes
+    assert eng.stats["prefill_computed_tokens"] == sum(
+        g * s for g, s in shapes)
+    assert eng.stats["prefill_computed_tokens"] > eng.stats["prefill_tokens"]
+    if "kv_share" in kw:
+        assert eng.stats["prefill_tokens_skipped"] > 0
+
+
+def _lowered_module_names(calls):
+    """Module names of jitted functions lowered with the arguments of
+    their first recorded call: {name: (jitted, args)}."""
+    out = {}
+    for name, (fn, args) in calls.items():
+        text = fn.lower(*args).as_text()
+        out[name] = text.split("module @", 1)[1].split(" ", 1)[0]
+    return out
+
+
+def _record_calls(obj, names):
+    """Wrap the jitted attributes ``names`` of ``obj`` to record their
+    first call: {name: (jitted, args)}."""
+    calls = {}
+    for name in names:
+        fn = getattr(obj, name)
+
+        def rec(*a, _fn=fn, _name=name):
+            calls.setdefault(_name, (_fn, a))
+            return _fn(*a)
+        setattr(obj, name, rec)
+    return calls
+
+
+ENGINE_JITS = ("_decode", "_prefill", "_prefill_past", "_sample", "_snap",
+               "_restore", "_draft_decode", "_verify")
+POOL_JITS = ("_read", "_write", "_scrub", "_copy", "_merge")
+
+
+def test_engine_and_pool_jits_compile_under_their_names():
+    cfg, params = _setup()
+    rng = np.random.default_rng(5)
+    # contiguous: admission, decode, a kept-KV preemption and its resume
+    eng = Engine(params, cfg, batch_slots=2, cache_len=64)
+    calls = _record_calls(eng, [n for n in ENGINE_JITS if hasattr(eng, n)])
+    for r in _mk_requests(2, rng):
+        eng.submit(r)
+    eng.step()
+    eng.queue.insert(0, eng.preempt_slot(0, keep_kv=True))
+    while eng.has_work():
+        eng.step()
+    names = _lowered_module_names(calls)
+    assert set(names) == {"_decode", "_prefill", "_sample", "_snap",
+                          "_restore"}
+    # paged, sharing, speculating: every engine program of that layout
+    peng = Engine(params, cfg, batch_slots=2, cache_len=64, **_SHARE,
+                  draft_sparsity=0.75, draft_k=4)
+    pcalls = _record_calls(peng, [n for n in ENGINE_JITS
+                                  if hasattr(peng, n)])
+    reqs = _mk_requests(3, rng)
+    for r in reqs[1:]:
+        r.prompt = np.concatenate([reqs[0].prompt[:16], r.prompt])
+    reqs[0].prompt = np.concatenate([reqs[0].prompt,
+                                     np.ones((16,), np.int32)])
+    peng.run(reqs)
+    names.update({"paged" + k: v for k, v in
+                  _lowered_module_names(pcalls).items()})
+    assert {"paged_decode", "paged_prefill", "paged_prefill_past",
+            "paged_draft_decode", "paged_verify"} <= set(names), names
+    # the page pool's programs, lowered on its own arrays
+    pool = peng.pool
+    ids = jax.numpy.asarray([2, 3], jax.numpy.int32)
+    one = jax.numpy.asarray(2, jax.numpy.int32)
+    pool_args = {
+        "_read": (pool._read, (pool.data, ids)),
+        "_write": (pool._write, (pool.data, ids,
+                                 pool._read(pool.data, ids))),
+        "_scrub": (pool._scrub, (pool.data, ids)),
+        "_copy": (pool._copy, (pool.data, one, one)),
+        "_merge": (pool._merge, (pool.data, one, one, one, one)),
+    }
+    names.update({"pool" + k: v for k, v in
+                  _lowered_module_names(pool_args).items()})
+    assert set(POOL_JITS) <= {k[len("pool"):] for k in names
+                              if k.startswith("pool")}
+    for k, v in names.items():
+        assert v.startswith("jit_") and "unknown" not in v \
+            and "lambda" not in v, (k, v)
+    assert names["_decode"] == "jit__decode_step"
+    assert names["paged_decode"] == "jit__paged_decode_step"
+    assert names["_prefill"] == "jit__prefill_and_write"
+    assert names["_sample"] == "jit__sample_tokens"
 
 
 def test_preempt_spill_resume_traced_and_bit_identical(tmp_path):

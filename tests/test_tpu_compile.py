@@ -10,6 +10,7 @@ each worker imports every test file, so only the worker that runs this
 file may load it. Keep every such compile in this one file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -93,3 +94,38 @@ def test_sasp_gemm_compiles_for_v5e(one_chip, wdt, M):
             return sasp_gemm(x, w, kn, n=N)
     out = _compile(fn, *args).out_info
     assert out.shape == (M, N) and out.dtype == jnp.bfloat16
+
+
+def _kernel_args(one_chip, kernel, M=4):
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    d, bf = D_MODEL, D_FF_BLOCK
+    if kernel == "sasp_ffn_fused":
+        return sasp_fused_ffn, [
+            s((M, d), jnp.bfloat16), s((NV, d, bf), jnp.bfloat16),
+            s((NV, d, bf), jnp.bfloat16), s((NV, bf, d), jnp.bfloat16),
+            s((NV, bf), jnp.float32), s((NV, bf), jnp.float32),
+            s((d,), jnp.float32)]
+
+    def fn(x, w, kn):
+        return sasp_gemm(x, w, kn, n=N)
+    return fn, [s((M, K), jnp.bfloat16), s((NNZ, 128, 128), jnp.bfloat16),
+                s((2, NNZ), jnp.int32)]
+
+
+@pytest.mark.parametrize("kernel", ["sasp_ffn_fused", "sasp_matmul"])
+def test_served_kernels_carry_their_names_for_v5e(one_chip, kernel):
+    """The Mosaic custom call names its kernel: ``kernel_name``, and the
+    ``kernel_metadata`` frontend attribute that the op text of a
+    profiler trace carries."""
+    fn, args = _kernel_args(one_chip, kernel)
+    lowered = jax.jit(fn).lower(*args)
+    assert f'kernel_name = "{kernel}"' in lowered.as_text()
+    # one instruction per chunk (kernel_metadata's JSON holds newlines)
+    text = _compile(fn, *args).as_text()
+    calls = [op for op in re.split(r"\n\s+(?:ROOT )?%", text)
+             if 'custom_call_target="tpu_custom_call"' in op]
+    assert len(calls) == 1
+    assert f'"kernel":"{kernel}"' in calls[0]
+    assert "frontend_attributes={kernel_metadata={" in calls[0]

@@ -615,9 +615,13 @@ class _BodyWalker:
 # jit-root discovery
 # ---------------------------------------------------------------------------
 
+# serve/engine.py's jit of a partial under the wrapped function's name
+NAMED_JIT = "named_jit"
+
+
 def _is_jit(node: ast.Call) -> bool:
     name = dotted_name(node.func)
-    return name in ("jax.jit", "jit") or (
+    return name in ("jax.jit", "jit", NAMED_JIT) or (
         name is not None and name.endswith(".jit")
         and name.startswith("jax"))
 
@@ -705,7 +709,10 @@ def _seed_roots(an: _Analyzer, corpus: Corpus,
                 continue
             n_static = 0
             target = wrapped
-            if (isinstance(wrapped, ast.Call)
+            if dotted_name(call.func) == NAMED_JIT:
+                # named_jit(f, s1, s2) is jax.jit(partial(f, s1, s2))
+                n_static = len(call.args) - 1
+            elif (isinstance(wrapped, ast.Call)
                     and (dotted_name(wrapped.func) or "").split(".")[-1]
                     == "partial"):
                 # jax.jit(partial(f, s1, s2, kw=...)): leading
