@@ -15,10 +15,12 @@ the serving path:
   attached under ``sasp_fused``.
 
 Layer stacks (the ``lax.scan``-over-layers layout, leading ``repeat``
-axis) are packed per layer and padded to one shared static nnz/nv so the
-containers slice under scan exactly like every other stacked param
+axis) are packed per layer and padded to one shared static nnz/nv
 (padding = duplicated last visit with zero values; see
-``kernels.sasp_gemm.ops.pad_block_list``).
+``kernels.sasp_gemm.ops.pad_block_list``). Per-matrix and TP-sharded
+containers slice under scan like every other stacked param; an
+unsharded fused container is read in place by layer index, never sliced
+(``models.lm._scan_layers``, DESIGN.md §9).
 
 Masks are recovered from the nonzero tile structure of the (already
 pruned) weights, so the conversion needs nothing beyond the deployed
@@ -320,13 +322,14 @@ def packed_matmul(x: jnp.ndarray, pw: PackedSASPWeight, *,
         bias=pw.bias, act=pw.act)
 
 
-def packed_ffn_apply(x: jnp.ndarray, pf: PackedFFN, *,
+def packed_ffn_apply(x: jnp.ndarray, pf: PackedFFN, *, layer=None,
                      block_m: int = 128) -> jnp.ndarray:
-    """Whole gated FFN in one fused kernel launch."""
+    """Whole gated FFN in one fused kernel launch. ``layer``: for a
+    layer-stacked container, the layer to run, read in place."""
     scales = None if pf.s1 is None else (pf.s1, pf.s3, pf.s2)
     return sasp_ops.fused_ffn_matmul(
         x, pf.w1v, pf.w3v, pf.w2v, pf.b1, pf.b3, pf.b2, scales=scales,
-        act=pf.act, block_m=block_m)
+        layer=layer, act=pf.act, block_m=block_m)
 
 
 # ---------------------------------------------------------------------------

@@ -214,8 +214,10 @@ class PackedFFN:
 
     w1v/w3v: (…, nv, d, bf); w2v: (…, nv, bf, d); b1/b3: (…, nv, bf);
     b2: (…, d); s1/s3/s2: optional (…, nv) int8 scales. A leading layer
-    axis makes it ``lax.scan``-sliceable (per-layer packs padded to one
-    shared nv with zero-w2v visits). Static aux: d_model, d_ff, block_f,
+    axis stacks the layers (per-layer packs padded to one shared nv with
+    zero-w2v visits); the layer scan does not slice an unsharded stack
+    but has the kernel read it in place by layer index
+    (``models.lm._scan_layers``). Static aux: d_model, d_ff, block_f,
     act.
 
     TP sharding (DESIGN.md §10): ``shards > 1`` adds a shard axis before

@@ -31,7 +31,9 @@ HBM, so serving-side bias+act costs zero extra memory traffic.
 (w1/w3 up-projections, gate product, w2 down-projection) runs through a
 single visit schedule over surviving d_ff column-blocks; the (M, d_ff)
 intermediate lives only as one (bm, bf) VMEM tile per visit and is never
-materialized in HBM.
+materialized in HBM. It also takes a layer stack and the layer to run,
+scalar-prefetched into its weight index maps: the model's layer scan
+hands it the whole stacked weights, and no per-layer copy is made.
 
 Block shapes default to MXU-aligned 128 multiples. Every call goes
 through ``kernels.dispatch.pallas_call``: Mosaic on a TPU, the Pallas
@@ -193,7 +195,8 @@ def sasp_gemm(x: jnp.ndarray, w_vals: jnp.ndarray, block_kn: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
-def _fused_ffn_kernel(*refs, nv: int, act: str, scaled: bool):
+def _fused_ffn_kernel(lyr_ref, *refs, nv: int, act: str, scaled: bool):
+    del lyr_ref                                  # read by the index maps
     refs = list(refs)
     sc_ref = refs.pop(0) if scaled else None     # (3, nv) f32, SMEM
     (x_ref, w1_ref, w3_ref, w2_ref, b1_ref, b3_ref, b2_ref, o_ref,
@@ -217,12 +220,12 @@ def _fused_ffn_kernel(*refs, nv: int, act: str, scaled: bool):
 
     @pl.when(s == nv - 1)
     def _flush():
-        o_ref[...] = (acc_ref[...] + b2_ref[...]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] + b2_ref[0]).astype(o_ref.dtype)
 
 
 def sasp_fused_ffn(x: jnp.ndarray, w1v: jnp.ndarray, w3v: jnp.ndarray,
                    w2v: jnp.ndarray, b1: jnp.ndarray, b3: jnp.ndarray,
-                   b2: jnp.ndarray, *, act: str = "silu",
+                   b2: jnp.ndarray, *, layer=None, act: str = "silu",
                    block_m: int = 128, scales=None,
                    out_dtype=None) -> jnp.ndarray:
     """Gated FFN through one Pallas visit schedule.
@@ -237,25 +240,47 @@ def sasp_fused_ffn(x: jnp.ndarray, w1v: jnp.ndarray, w3v: jnp.ndarray,
     ops.build_fused_ffn relies on act(0) == 0. The gate product h is
     rounded to x's dtype before the down-projection, as a dense FFN in
     that dtype rounds it.
+
+    Layer stacks: with a leading layer axis on every operand — w1v/w3v
+    (L, nv, d, bf), w2v (L, nv, bf, d), b1/b3 (L, nv, bf), b2 (L, d),
+    scales (L, nv) each — the kernel runs layer ``layer`` (a scalar,
+    traced or not) and reads that layer's visit blocks straight out of
+    the stacked arrays: the layer is scalar-prefetched and offsets the
+    weight index maps, so no per-layer slice of the weights is made.
+    Un-stacked operands are a stack of one, layer 0.
     Returns (M, d) = act(x@W1+b1) * (x@W3+b3) @ W2 + b2.
     """
+    if w1v.ndim == 3:                      # one layer: a stack of one
+        w1v, w3v, w2v, b1, b3, b2 = (a[None] for a in
+                                     (w1v, w3v, w2v, b1, b3, b2))
+        scales = None if scales is None else tuple(
+            sc[None] for sc in scales)
+        layer = 0
     M, d = x.shape
-    nv, d2, bf = w1v.shape
-    assert d2 == d and w2v.shape == (nv, bf, d), (w1v.shape, w2v.shape)
+    L, nv, d2, bf = w1v.shape
+    assert d2 == d and w2v.shape == (L, nv, bf, d), (w1v.shape, w2v.shape)
     assert act in _ACTS and act is not None
     bm, Mp = _row_blocks(M, block_m)
     out_dtype = out_dtype or x.dtype
     scaled = scales is not None
 
+    # The weights are passed whole, with layer and visit axes merged
+    # (a free reshape): visit s of layer l is block l * nv + s.
+    def visit(i, s, lyr, *_):
+        return (lyr[0] * nv + s, 0, 0)
+
     x_spec = pl.BlockSpec((bm, d), lambda i, s, *_: (i, 0))
-    up_spec = pl.BlockSpec((1, d, bf), lambda i, s, *_: (s, 0, 0))
-    dn_spec = pl.BlockSpec((1, bf, d), lambda i, s, *_: (s, 0, 0))
+    up_spec = pl.BlockSpec((1, d, bf), visit)
+    dn_spec = pl.BlockSpec((1, bf, d), visit)
     # (1, 1, bf): the last two block dims equal the array's (1, bf)
-    bu_spec = pl.BlockSpec((1, 1, bf), lambda i, s, *_: (s, 0, 0))
-    b2_spec = pl.BlockSpec((1, d), lambda i, s, *_: (0, 0))
+    bu_spec = pl.BlockSpec((1, 1, bf), visit)
+    b2_spec = pl.BlockSpec((1, 1, d), lambda i, s, lyr, *_: (lyr[0], 0, 0))
     o_spec = pl.BlockSpec((bm, d), lambda i, s, *_: (i, 0))
 
-    prefetch = [jnp.stack(scales).astype(jnp.float32)] if scaled else []
+    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
+    # int8 scales: this layer's (3, nv), a few KB, sliced here
+    prefetch = [lyr] + ([jnp.stack(scales)[:, layer].astype(jnp.float32)]
+                        if scaled else [])
     return pallas_call(
         functools.partial(_fused_ffn_kernel, nv=nv, act=act,
                           scaled=scaled),
@@ -270,10 +295,11 @@ def sasp_fused_ffn(x: jnp.ndarray, w1v: jnp.ndarray, w3v: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, d), out_dtype),
         compiler_params=_PARAMS,
-    )(*prefetch, _pad_rows(x, Mp), w1v, w3v, w2v,
-      b1.astype(jnp.float32).reshape(nv, 1, bf),
-      b3.astype(jnp.float32).reshape(nv, 1, bf),
-      b2.astype(jnp.float32).reshape(1, d))[:M]
+    )(*prefetch, _pad_rows(x, Mp), w1v.reshape(L * nv, d, bf),
+      w3v.reshape(L * nv, d, bf), w2v.reshape(L * nv, bf, d),
+      b1.astype(jnp.float32).reshape(L * nv, 1, bf),
+      b3.astype(jnp.float32).reshape(L * nv, 1, bf),
+      b2.astype(jnp.float32).reshape(L, 1, d))[:M]
 
 
 # ---------------------------------------------------------------------------

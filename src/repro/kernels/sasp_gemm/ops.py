@@ -198,18 +198,21 @@ def build_fused_ffn(w1: np.ndarray, w3: np.ndarray, w2: np.ndarray, *,
 
 
 @functools.partial(jax.jit, static_argnames=("act", "block_m"))
-def _fused_ffn_jit(x, w1v, w3v, w2v, b1, b3, b2, scales, *, act, block_m):
-    return sasp_fused_ffn(x, w1v, w3v, w2v, b1, b3, b2, act=act,
-                          block_m=block_m, scales=scales)
+def _fused_ffn_jit(x, w1v, w3v, w2v, b1, b3, b2, scales, layer, *, act,
+                   block_m):
+    return sasp_fused_ffn(x, w1v, w3v, w2v, b1, b3, b2, layer=layer,
+                          act=act, block_m=block_m, scales=scales)
 
 
 def fused_ffn_matmul(x: jnp.ndarray, w1v, w3v, w2v, b1, b3, b2, *,
-                     scales=None, act: str = "silu",
+                     scales=None, layer=None, act: str = "silu",
                      block_m: int = 128) -> jnp.ndarray:
-    """(…, d) -> (…, d) gated FFN through the single fused kernel."""
+    """(…, d) -> (…, d) gated FFN through the single fused kernel.
+    ``layer``: for layer-stacked operands, the layer to run (see
+    ``kernel.sasp_fused_ffn``)."""
     *lead, d = x.shape
     y = _fused_ffn_jit(x.reshape(-1, d), w1v, w3v, w2v, b1, b3, b2,
-                       scales, act=act, block_m=block_m)
+                       scales, layer, act=act, block_m=block_m)
     return y.reshape(*lead, d).astype(x.dtype)
 
 

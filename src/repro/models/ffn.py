@@ -17,6 +17,10 @@ Execution paths (DESIGN.md §4):
                          whole-FFN "sasp_fused" PackedFFN (one kernel
                          launch, no HBM (M, d_ff) intermediate). Zero
                          per-call repacking — the serving fast path.
+                         Inside the layer scan an unsharded
+                         "sasp_fused" stays stacked and comes with
+                         "sasp_fused_layer", the layer the kernel reads
+                         in place (models/lm.py ``_scan_layers``).
   * quant              — weight-only INT8 (+ per-block scales); composes
                          with any of the above.
 """
@@ -388,7 +392,7 @@ def _ffn_apply_packed(p: Dict, cfg: ModelConfig, x2: jnp.ndarray
     if fused is not None:
         if fused.shards > 1:
             return _packed_ffn_fused_sharded(x2, fused, cfg)
-        return packed_ffn_apply(x2, fused)
+        return packed_ffn_apply(x2, fused, layer=p.get("sasp_fused_layer"))
     packed = p.get("sasp_packed")
     if packed is not None and "w1" in packed:
         if packed["w1"].shards > 1:

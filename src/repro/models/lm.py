@@ -27,6 +27,7 @@ from repro.configs.base import (
     MIXER_ATTN,
     ModelConfig,
 )
+from repro.core.sparse import PackedFFN
 from repro.models import attention as attn_mod
 from repro.models import ffn as ffn_mod
 from repro.models import moe as moe_mod
@@ -215,6 +216,42 @@ def _maybe_remat(fn, cfg: ModelConfig):
     return jax.checkpoint(fn)
 
 
+def _scan_layers(body, carry, seg_params, xs=None):
+    """``lax.scan`` of ``body(carry, (slot_params, xs_i))`` over one
+    segment's stacked layers — the one scan every runner uses.
+
+    An unsharded fused FFN container (``sasp_fused``, ``shards == 1``)
+    is not scanned: a scanned operand reaches the body as a per-layer
+    slice, which XLA copies before the kernel reads it (three weight
+    copies per layer per program). The body closes over the whole stack
+    instead, and each layer's FFN gets the stack with its layer index
+    (``sasp_fused_layer``), which the kernel's index maps read in place.
+    Without such a container this is the plain scan."""
+    scanned, held = dict(seg_params), {}
+    for slot, sp in seg_params.items():
+        ffn = sp.get("ffn")
+        fused = ffn.get("sasp_fused") if isinstance(ffn, dict) else None
+        if isinstance(fused, PackedFFN) and fused.shards == 1:
+            held[slot] = fused
+            rest = {k: v for k, v in ffn.items() if k != "sasp_fused"}
+            scanned[slot] = {**sp, "ffn": rest}
+    if len(held) == 0:
+        return jax.lax.scan(body, carry, (seg_params, xs))
+    repeat = next(iter(held.values())).w1v.shape[0]
+
+    def layered(c, inp):
+        slot_params, layer, xs_i = inp
+        slot_params = dict(slot_params)
+        for slot, fused in held.items():
+            ffn = {**slot_params[slot]["ffn"], "sasp_fused": fused,
+                   "sasp_fused_layer": layer}
+            slot_params[slot] = {**slot_params[slot], "ffn": ffn}
+        return body(c, (slot_params, xs_i))
+
+    return jax.lax.scan(layered, carry,
+                        (scanned, jnp.arange(repeat, dtype=jnp.int32), xs))
+
+
 def _run_segments_full(params, cfg: ModelConfig, x, positions,
                        want_cache: bool, cache_len: int,
                        uniform_cache: bool = False):
@@ -223,8 +260,9 @@ def _run_segments_full(params, cfg: ModelConfig, x, positions,
     all_caches = []
     for seg_params, (pattern, repeat) in zip(params["segments"], plan):
 
-        def body(carry, slot_params):
+        def body(carry, inp):
             from repro.distribution import context as dctx
+            slot_params, _ = inp
             xc, aux = carry
             xc = dctx.shard_batch(xc)
             caches = {}
@@ -238,7 +276,7 @@ def _run_segments_full(params, cfg: ModelConfig, x, positions,
             return (xc, aux), caches
 
         body = _maybe_remat(body, cfg)
-        (x, aux_total), seg_caches = jax.lax.scan(
+        (x, aux_total), seg_caches = _scan_layers(
             body, (x, aux_total), seg_params)
         all_caches.append(seg_caches)
     return x, aux_total, tuple(all_caches) if want_cache else None
@@ -302,7 +340,7 @@ def _run_segments_prefill_past(params, cfg: ModelConfig, x, positions,
             return xc, out_caches
 
         body = _maybe_remat(body, cfg)
-        x, seg_new = jax.lax.scan(body, x, (seg_params, seg_past))
+        x, seg_new = _scan_layers(body, x, seg_params, seg_past)
         new_caches.append(seg_new)
     return x, tuple(new_caches)
 
@@ -347,7 +385,7 @@ def _run_segments_decode(params, cfg: ModelConfig, x, pos, caches):
                 out_caches[f"slot{slot}"] = c
             return xc, out_caches
 
-        x, seg_new = jax.lax.scan(body, x, (seg_params, seg_caches))
+        x, seg_new = _scan_layers(body, x, seg_params, seg_caches)
         new_caches.append(seg_new)
     return x, tuple(new_caches)
 

@@ -377,3 +377,199 @@ def test_engine_temperature_sampling_on_device():
         assert all(0 <= t < cfg.vocab_size for t in r.out_tokens)
         outs.add(tuple(r.out_tokens))
     assert len(outs) > 1              # different seeds, different streams
+
+
+# ---------------------------------------------------------------------------
+# The layer scan reads each layer's fused FFN container in place
+# (models/lm.py ``_scan_layers``): same arithmetic as handing each layer
+# its own slice, and the same program as before for everything else.
+# ---------------------------------------------------------------------------
+
+
+def _in_place_model(quantize: bool):
+    """qwen3-shaped, 3 layers at d 256, bf16, FFN tiles pruned at 0.5 on
+    64×64 blocks (8 d_ff blocks), packed into the fused container. w2 is
+    rescaled to w1's magnitude first, so global pruning leaves live w2
+    tiles (as chip_smoke.py does)."""
+    sasp = SASPConfig(enabled=True, block_k=64, block_n=64, sparsity=0.5,
+                      scope="ffn")
+    cfg = dataclasses.replace(
+        reduced(get_config("qwen3-32b"), layers=3, d_model=256, vocab=64),
+        sasp=sasp, param_dtype="bfloat16", compute_dtype="bfloat16")
+    params = lm.init_params(KEY, cfg)
+    ffn = params["segments"][0]["slot0"]["ffn"]
+    w1, w2 = ffn["w1"]["w"], ffn["w2"]["w"]
+    r = jnp.mean(jnp.abs(w1.astype(jnp.float32))) / jnp.mean(
+        jnp.abs(w2.astype(jnp.float32)))
+    ffn["w2"] = {"w": (w2.astype(jnp.float32) * r).astype(w2.dtype)}
+    pruned, _ = prune_params(params, sasp)
+    pp, pcfg = deploy_packed(pruned, cfg, quantize=quantize)
+    fused = pp["segments"][0]["slot0"]["ffn"]["sasp_fused"]
+    assert fused.shards == 1 and fused.w1v.shape[0] == 3
+    assert _live_visits(fused.w2v) > 0           # real work in the FFN
+    return pp, pcfg
+
+
+def _layer_loop(params, cfg, x, caches, run_slot):
+    """Reference for the layer scan: a Python loop over layers, each
+    handed its own slice of every stacked param, so the fused FFN runs
+    on a 3-D container (the kernel's stack of one)."""
+    plan = lm.segment_plan(cfg)
+    caches = caches or (None,) * len(plan)
+    out = []
+    for seg, seg_c, (pattern, repeat) in zip(params["segments"], caches,
+                                             plan):
+        per_layer = []
+        for i in range(repeat):
+            sp = jax.tree.map(lambda a: a[i], seg)
+            c = None if seg_c is None else jax.tree.map(lambda a: a[i],
+                                                        seg_c)
+            new = {}
+            for slot, spec in enumerate(pattern):
+                name = f"slot{slot}"
+                x, new[name] = run_slot(sp[name], spec, x,
+                                        None if c is None else c[name])
+            per_layer.append(new)
+        out.append(jax.tree.map(lambda *a: jnp.stack(a), *per_layer))
+    return x, tuple(out)
+
+
+def _loop_prefill(params, cfg, tokens, cache_len):
+    x = lm._embed_in(params, cfg, tokens, None)
+    positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
+
+    def run(sp, spec, x, _):
+        x, _, c = lm._apply_slot_full(sp, spec, cfg, x, positions, True,
+                                      cache_len)
+        return x, c
+    x, caches = _layer_loop(params, cfg, x, None, run)
+    return lm.logits_fn(params, cfg, x[:, -1:]), caches
+
+
+def _loop_decode(params, cfg, tokens, pos, caches):
+    x = lm._embed_in(params, cfg, tokens, None)
+    x, caches = _layer_loop(
+        params, cfg, x, caches, lambda sp, spec, x, c:
+        lm._apply_slot_decode(sp, spec, cfg, x, pos, c))
+    return lm.logits_fn(params, cfg, x), caches
+
+
+def _loop_prefill_past(params, cfg, tokens, positions, past):
+    x = lm._embed_in(params, cfg, tokens, None)
+    x, caches = _layer_loop(
+        params, cfg, x, past, lambda sp, spec, x, c:
+        lm._apply_slot_prefill_past(sp, spec, cfg, x, positions, c))
+    return lm.logits_fn(params, cfg, x[:, -1:]), caches
+
+
+def _assert_trees_equal(a, b):
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def _sliced_scan(body, carry, seg_params, xs=None):
+    """The layer scan as it was: every param scanned, so each layer's
+    fused FFN container arrives as a per-layer slice."""
+    return jax.lax.scan(body, carry, (seg_params, xs))
+
+
+# Every bf16 value rounded where the program computes it: XLA may keep
+# bf16 intermediates in float32, and does so differently in a loop body
+# and in straight-line code.
+_NO_EXCESS = {"xla_allow_excess_precision": False}
+
+
+@pytest.mark.parametrize("ref", ["sliced_scan", "layer_loop"])
+@pytest.mark.parametrize("quantize", [False, True], ids=["bf16", "int8"])
+def test_in_place_fused_ffn_matches_per_layer_slices(monkeypatch,
+                                                     quantize, ref):
+    """Prefill, decode and suffix prefill through the layer scan, which
+    reads the stacked fused FFN container in place by layer index, are
+    bit-identical to handing each layer its own slice: the scan that
+    slices every param, or a Python loop over layers."""
+    pp, cfg = _in_place_model(quantize)
+    assert not cfg.logit_softcap
+    rng = np.random.default_rng(0)
+    toks = jnp.asarray(rng.integers(1, 64, (2, 8)), jnp.int32)
+    step = jnp.asarray([[3], [5]], jnp.int32)
+    pos = jnp.asarray([8, 8], jnp.int32)
+    suffix = jnp.asarray(rng.integers(1, 64, (2, 4)), jnp.int32)
+    spos = jnp.broadcast_to(jnp.arange(9, 13, dtype=jnp.int32), (2, 4))
+    programs = {
+        "prefill": lambda p, c: lm.prefill(p, cfg, toks, cache_len=16),
+        "decode": lambda p, c: lm.decode_step(p, cfg, step, pos, c),
+        "prefill_past": lambda p, c: lm.prefill_with_past(p, cfg, suffix,
+                                                          spos, c),
+    }
+    opts = None
+    if ref == "layer_loop":
+        opts = _NO_EXCESS
+        refs = {
+            "prefill": lambda p, c: _loop_prefill(p, cfg, toks, 16),
+            "decode": lambda p, c: _loop_decode(p, cfg, step, pos, c),
+            "prefill_past": lambda p, c: _loop_prefill_past(
+                p, cfg, suffix, spos, c),
+        }
+    caches = jax.jit(programs["prefill"])(pp, None)[1]
+    got = {k: jax.jit(f, compiler_options=opts)(pp, caches)
+           for k, f in programs.items()}
+    if ref == "sliced_scan":
+        monkeypatch.setattr(lm, "_scan_layers", _sliced_scan)
+        jax.clear_caches()              # trace the programs again
+        refs = programs
+    for k, f in refs.items():
+        _assert_trees_equal(got[k], jax.jit(f, compiler_options=opts)(
+            pp, caches))
+
+
+@pytest.mark.parametrize("entry", ["prefill", "decode", "prefill_past",
+                                   "loss"])
+@pytest.mark.parametrize("tree", ["dense", "masked", "packed_per_matrix"])
+def test_programs_without_fused_ffn_unchanged(monkeypatch, tree, entry):
+    """A param tree without an unsharded fused FFN container traces to
+    the same program as a scan that keeps every param in its scanned
+    operands: the in-place read changes nothing else."""
+    pruned, cfg = _pruned(scope="all", sparsity=0.25)
+    if tree == "dense":
+        params, cfg = lm.init_params(KEY, cfg), dataclasses.replace(
+            cfg, sasp=SASPConfig())
+    elif tree == "masked":
+        params = pruned
+    else:
+        params, cfg = deploy_packed(pruned, cfg, fuse_ffn=False)
+    toks = jnp.asarray(np.arange(1, 9, dtype=np.int32)[None])
+    _, caches = lm.prefill(params, cfg, toks, cache_len=16)
+    pos = jnp.asarray([8], jnp.int32)
+    spos = jnp.arange(8, 10, dtype=jnp.int32)[None]
+    fns = {
+        "prefill": lambda p: lm.prefill(p, cfg, toks, cache_len=16),
+        "decode": lambda p: lm.decode_step(p, cfg, toks[:, :1], pos,
+                                           caches),
+        "prefill_past": lambda p: lm.prefill_with_past(
+            p, cfg, toks[:, :2], spos, caches),
+        "loss": lambda p: lm.loss_fn(p, cfg, {"tokens": toks})[0],
+    }
+    got = str(jax.make_jaxpr(fns[entry])(params))
+    monkeypatch.setattr(lm, "_scan_layers", _sliced_scan)
+    assert str(jax.make_jaxpr(fns[entry])(params)) == got
+
+
+def test_layer_scan_does_not_slice_fused_ffn():
+    """The decode program's layer scan carries the stacked fused FFN
+    weights as loop-invariant operands, not as per-layer slices."""
+    pp, cfg = _in_place_model(False)
+    fused = pp["segments"][0]["slot0"]["ffn"]["sasp_fused"]
+    _, caches = lm.prefill(pp, cfg, jnp.ones((1, 4), jnp.int32),
+                           cache_len=8)
+    jaxpr = jax.make_jaxpr(lambda p: lm.decode_step(
+        p, cfg, jnp.ones((1, 1), jnp.int32), jnp.asarray([4], jnp.int32),
+        caches))(pp)
+    (scan,) = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    nc = scan.params["num_consts"]
+    nx = len(scan.invars) - nc - scan.params["num_carry"]
+    consts = [v.aval.shape for v in scan.invars[:nc]]
+    xs = [v.aval.shape for v in scan.invars[-nx:]]
+    for w in (fused.w1v, fused.w3v, fused.w2v):
+        assert w.shape in consts and w.shape not in xs

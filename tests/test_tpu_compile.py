@@ -129,3 +129,73 @@ def test_served_kernels_carry_their_names_for_v5e(one_chip, kernel):
     assert len(calls) == 1
     assert f'"kernel":"{kernel}"' in calls[0]
     assert "frontend_attributes={kernel_metadata={" in calls[0]
+
+
+LAYERS, NV_L = 4, 8                      # a 4-layer stack, 8 visits each
+
+
+def _stacked_ffn(one_chip, wdt):
+    from repro.core.sparse import PackedFFN
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    L, nv, d, bf = LAYERS, NV_L, D_MODEL, D_FF_BLOCK
+    sc = s((L, nv), jnp.float32) if wdt == jnp.int8 else None
+    return PackedFFN(s((L, nv, d, bf), wdt), s((L, nv, d, bf), wdt),
+                     s((L, nv, bf, d), wdt), s((L, nv, bf), jnp.float32),
+                     s((L, nv, bf), jnp.float32), s((L, d), jnp.float32),
+                     d_model=d, d_ff=25600, block_f=bf, act="silu",
+                     s1=sc, s3=sc, s2=sc)
+
+
+def _result_shapes(text):
+    """Each instruction's result dims, by the instruction's text."""
+    return re.findall(r"^\s*(?:ROOT )?%\S+ = [a-z0-9]+\[([0-9,]*)\]",
+                      text, re.M)
+
+
+@pytest.mark.parametrize("scan", ["in_place", "sliced"])
+@pytest.mark.parametrize("wdt", [jnp.bfloat16, jnp.int8])
+def test_layer_scan_reads_fused_ffn_in_place_for_v5e(one_chip, wdt, scan,
+                                                     monkeypatch):
+    """A 4-layer scan of the fused FFN through the model step's layer
+    scan (models/lm.py ``_scan_layers``) hands the kernel the whole
+    stacked weights: no op makes a single layer's (nv, d, bf) or
+    (nv, bf, d) copy, and the kernel's operands are the stacks, layer
+    and visit axes merged. The scan that slices every param (the
+    ``sliced`` control) makes those copies, which shows the check sees
+    them."""
+    from repro.configs import get_config
+    from repro.models import ffn as ffn_mod
+    from repro.models import lm
+
+    if scan == "sliced":
+        monkeypatch.setattr(
+            lm, "_scan_layers", lambda body, carry, seg, xs=None:
+            jax.lax.scan(body, carry, (seg, xs)))
+    cfg = get_config("qwen3-32b")
+
+    def step(x, seg):
+        def body(xc, inp):
+            return ffn_mod.ffn_apply(inp[0]["slot0"]["ffn"], cfg, xc), None
+        return lm._scan_layers(body, x, seg)[0]
+
+    seg = {"slot0": {"ffn": {"sasp_fused": _stacked_ffn(one_chip, wdt)}}}
+    x = jax.ShapeDtypeStruct((12, D_MODEL), jnp.bfloat16,
+                             sharding=one_chip)
+    text = jax.jit(step).lower(x, seg).compile().as_text()
+    assert text.count(" while(") == 1    # the layer scan; Mosaic kernel
+    one = {f"{NV_L},{D_MODEL},{D_FF_BLOCK}", f"{NV_L},{D_FF_BLOCK},{D_MODEL}"}
+    sliced = one & set(_result_shapes(text))
+    calls = [op for op in re.split(r"\n\s+(?:ROOT )?%", text)
+             if 'custom_call_target="tpu_custom_call"' in op]
+    assert len(calls) == 1
+    stacked = (f"[{LAYERS * NV_L},{D_MODEL},{D_FF_BLOCK}]",
+               f"[{LAYERS * NV_L},{D_FF_BLOCK},{D_MODEL}]")
+    if scan == "sliced":
+        assert sliced
+        return
+    assert not sliced
+    operands = calls[0].split("operand_layout_constraints=")[1]
+    assert all(sh in operands for sh in stacked)
